@@ -18,8 +18,8 @@ workload x NoC:
   backend, plus ``fused_round_delta_us`` on the fused rows (the
   wall-clock/round win over the unfused pallas path).  In interpret mode
   the Pallas path pays the interpreter tax on CPU; the columns exist to
-  track that overhead (and, on a real TPU with
-  ``pallas_interpret=False``, the win) release over release.
+  track that overhead (and, once the TPU compiler accepts the kernels,
+  the win) release over release.
 
 The backend strings are "xla", "pallas-nofuse" (``pallas_fuse=False``:
 one kernel per building block + XLA glue) and "pallas" (the fused

@@ -19,7 +19,7 @@ time:
 Launch counts per variant are *measured* (the ``repro.kernels.engine.
 launches`` tally around an abstract trace), not hardcoded — the fused
 variants must count exactly 1.  Wall-clock columns are machine-dependent
-(and, under ``interpret=True`` on CPU, interpreter-taxed); the
+(and, on the CPU, where the kernels are interpreted, interpreter-taxed); the
 deterministic ``launches`` column is what the smoke baseline keeps.
 """
 from __future__ import annotations
@@ -33,8 +33,9 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.engine import (edge_scan_gather, fifo_turn, fold_scatter,
                                   frontier_pop, frontier_take,
-                                  fused_leg_call, queue_push_pop, record,
-                                  scatter_body, segment_gather, tally)
+                                  fused_leg_call, interpret_mode,
+                                  queue_push_pop, record, scatter_body,
+                                  segment_gather, tally)
 
 _K_MAX = 16     # frontier pop bound / queue pop budget
 _MAX_T2 = 8     # edge-scan bound
@@ -44,29 +45,29 @@ def _bump_kernel(x_ref, y_ref):
     y_ref[...] = x_ref[...] + 1
 
 
-def _bump(x, interpret=True):
+def _bump(x):
     record()  # raw pallas_call: tally it like the library wrappers do
     return pl.pallas_call(
         _bump_kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret)(x)
+        interpret=interpret_mode())(x)
 
 
-def _chain_unfused(n, interpret):
+def _chain_unfused(n):
     def fn(x):
         for _ in range(n):
-            x = _bump(x, interpret)
+            x = _bump(x)
         return x
     return fn
 
 
-def _chain_fused(n, interpret):
+def _chain_fused(n):
     def body(x):
         for _ in range(n):
             x = x + 1
         return x
 
     def fn(x):
-        return fused_leg_call(body, x, interpret=interpret)
+        return fused_leg_call(body, x)
     return fn
 
 
@@ -87,7 +88,7 @@ def _leg_inputs(v_chunk, e_chunk, cap, seed=0):
     )
 
 
-def _leg(fused, interpret):
+def _leg(fused):
     """The classic leg chain on one tile: pop -> turn -> gather -> fold.
     ``fused=False`` launches PR4's four standalone kernels; ``fused=True``
     composes the pure bodies and will be run inside ONE fused_leg_call."""
@@ -106,25 +107,23 @@ def _leg(fused, interpret):
             out = scatter_body(target, lidx, w.reshape(-1), jv.reshape(-1),
                                "min")
         else:
-            vidx, vvalid, mask2 = frontier_pop(mask, budget, _K_MAX,
-                                               interpret=interpret)
+            vidx, vvalid, mask2 = frontier_pop(mask, budget, _K_MAX)
             taken, tvalid, qdata2, qcount2, drops = queue_push_pop(
-                qdata, qcount, rows, rvalid, budget, _K_MAX,
-                interpret=interpret)
+                qdata, qcount, rows, rvalid, budget, _K_MAX)
             nb, w, jv = edge_scan_gather(
                 edge_dst, edge_val, taken[:, 0], taken[:, 1], tvalid,
-                _MAX_T2, interpret=interpret)
+                _MAX_T2)
             lidx = jnp.where(jv, nb % target.shape[0],
                              target.shape[0]).reshape(-1)
             out = fold_scatter(target, lidx, w.reshape(-1), jv.reshape(-1),
-                               op="min", interpret=interpret)
+                               op="min")
         return vidx, vvalid, mask2, qdata2, qcount2, drops, out
 
     if not fused:
         return chain
 
     def one_launch(*args):
-        return fused_leg_call(chain, *args, interpret=interpret)
+        return fused_leg_call(chain, *args)
     return one_launch
 
 
@@ -148,7 +147,7 @@ def _best_wall(fn, args, repeat):
 
 
 def run(n_chain: int = 32, size: int = 1024, repeat: int = 3,
-        interpret: bool = True, timing: bool = True) -> list[dict]:
+        timing: bool = True) -> list[dict]:
     """``timing=False`` drops the machine-dependent wall-clock columns
     (what smoke.py commits to the baseline; the measured ``launches``
     column stays)."""
@@ -156,8 +155,8 @@ def run(n_chain: int = 32, size: int = 1024, repeat: int = 3,
 
     # --- bump chain: N launches vs 1 ---------------------------------
     x = jnp.zeros((size,), jnp.int32)
-    un = _chain_unfused(n_chain, interpret)
-    fu = _chain_fused(n_chain, interpret)
+    un = _chain_unfused(n_chain)
+    fu = _chain_fused(n_chain)
     l_un = _count_launches(un, x)
     l_fu = _count_launches(fu, x)
     w_un = _best_wall(un, (x,), repeat) if timing else None
@@ -176,8 +175,8 @@ def run(n_chain: int = 32, size: int = 1024, repeat: int = 3,
     # --- one classic channel leg: 4 launches vs 1 ---------------------
     ins = _leg_inputs(v_chunk=size, e_chunk=4 * size, cap=4 * _K_MAX)
     args = tuple(ins.values())
-    leg4 = _leg(fused=False, interpret=interpret)
-    leg1 = _leg(fused=True, interpret=interpret)
+    leg4 = _leg(fused=False)
+    leg1 = _leg(fused=True)
     l4 = _count_launches(leg4, *args)
     l1 = _count_launches(leg1, *args)
     w4 = _best_wall(leg4, args, repeat) if timing else None

@@ -15,6 +15,9 @@ def main() -> None:
     t0 = time.time()
     fast = "--fast" in sys.argv
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (fig5_ablation, fig6_scaling, fig7_throughput,
                             fig8_noc, fig10_energy, fig11_backend,
                             fig12_serving, fig13_memspace,

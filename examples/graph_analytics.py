@@ -29,6 +29,7 @@ import functools
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.dalorex_graph import PRESETS
 from repro.core import algorithms as alg
 from repro.core import reference as ref
@@ -66,6 +67,7 @@ def main():
                          "(results stay bit-identical; see DESIGN.md "
                          "'Tracing & observability')")
     args = ap.parse_args()
+    enable_compile_cache()
     wl = PRESETS[args.preset] if args.preset else None
     scale = args.scale if args.scale is not None else \
         (wl.scale if wl else 11)

@@ -25,10 +25,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.comm import AxisComm, LocalComm, shard_map_compat
+from repro.core.comm import AxisComm, LocalComm
 from repro.core.engine import (BFS, PAGERANK, SPMV, SSSP, WCC, AlgSpec,
                                EngineConfig, EngineState, GraphShard, INF,
-                               Stats, init_state, run_engine, zero_stats)
+                               Stats, check_finished, init_state,
+                               run_engine, zero_stats)
 from repro.core.graph import CSRGraph, PartitionedGraph, partition_graph
 from repro.core.program import (TRIANGLES, as_program, kcore_program,
                                 sized_cfg)
@@ -102,13 +103,15 @@ def _local_call(prog, cfg: EngineConfig, T: int, e_chunk: int,
                 v_chunk: int, shard: GraphShard, value, frontier, acc):
     comm = LocalComm(T)
     st = init_state(comm, cfg, v_chunk, value, frontier, prog, acc)
-    st, stats, trace = run_engine(comm, cfg, prog, shard, st, e_chunk,
-                                  v_chunk)
-    return st.value, st.acc, stats, trace
+    st, stats, trace, pending = run_engine(comm, cfg, prog, shard, st,
+                                           e_chunk, v_chunk)
+    return st.value, st.acc, stats, trace, pending
 
 
 def local_engine_call(pg: PartitionedGraph, alg, cfg: EngineConfig,
                       value, frontier, acc=None):
+    """One engine run under LocalComm: ``(value, acc, stats, trace,
+    pending)``, with ``pending`` nonzero when ``max_rounds`` cut it."""
     prog = as_program(alg)
     shard = GraphShard(pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val)
     if acc is None:
@@ -123,7 +126,9 @@ def spmd_engine_call(pg: PartitionedGraph, alg, cfg: EngineConfig,
 
     Arrays keep the (T, chunk) layout; the leading axis is sharded so each
     device owns one tile row.  Inside, blocks are squeezed to per-device
-    shards and the identical engine code runs with ``AxisComm``.
+    shards and the identical engine code runs with ``AxisComm``.  Returns
+    the same ``(value, acc, stats, trace, pending)`` as
+    :func:`local_engine_call`.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -138,19 +143,20 @@ def spmd_engine_call(pg: PartitionedGraph, alg, cfg: EngineConfig,
         shard = GraphShard(ptr_start[0], deg[0], edge_dst[0], edge_val[0])
         st = init_state(comm, cfg, pg.v_chunk, value[0], frontier[0],
                         prog, acc[0])
-        st, stats, trace = run_engine(comm, cfg, prog, shard, st,
-                                      pg.e_chunk, pg.v_chunk)
-        return st.value[None], st.acc[None], stats, trace
+        st, stats, trace, pending = run_engine(comm, cfg, prog, shard, st,
+                                               pg.e_chunk, pg.v_chunk)
+        return st.value[None], st.acc[None], stats, trace, pending
 
     # the recorder's ring holds only global (replicated) series, so its
     # out_spec is P() everywhere, exactly like Stats (None when trace off)
     trace_spec = jax.tree.map(lambda _: P(), zero_trace(cfg, T, prog)) \
         if cfg.trace else None
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec2,) * 7,
         out_specs=(spec2, spec2, jax.tree.map(lambda _: P(), Stats.zero()),
-                   trace_spec))
+                   trace_spec, P()),
+        check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, spec2)) for a in
             (pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val, value,
              frontier, acc)]
@@ -170,9 +176,16 @@ class Result:
 
 
 def _call(pg, alg, cfg, value, frontier, mesh=None, axis="x", acc=None):
+    """One engine run on either comm path: ``(value, acc, stats, trace)``.
+    A run that ``max_rounds`` cut with work still pending raises."""
     if mesh is None:
-        return local_engine_call(pg, alg, cfg, value, frontier, acc)
-    return spmd_engine_call(pg, alg, cfg, value, frontier, mesh, axis, acc)
+        out = local_engine_call(pg, alg, cfg, value, frontier, acc)
+    else:
+        out = spmd_engine_call(pg, alg, cfg, value, frontier, mesh, axis,
+                               acc)
+    *result, pending = out
+    check_finished(cfg, pending, f"program {as_program(alg).name!r}")
+    return result
 
 
 def bfs(pg: PartitionedGraph, root: int, cfg: EngineConfig = EngineConfig(),

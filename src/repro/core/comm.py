@@ -96,13 +96,3 @@ class LocalComm:
         """Collapse a broadcast (T, ...) per-device value to one copy."""
         return x[0]
 
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (>=0.6 top-level, older
-    versions ship it as ``jax.experimental.shard_map`` with ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import shard_map_compat
 from repro.core.queues import occurrence_index
 from repro.parallel.sharding import current_mesh, current_rules
 
@@ -110,10 +109,10 @@ def routed_embed(table, ids, *, model_axis: str = "model",
         return emb, jax.lax.psum(ovf, model_axis)
 
     out_emb_spec = P(bspec, sspec, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(model_axis, None), P(bspec, sspec)),
-        out_specs=(out_emb_spec, P()))
+        out_specs=(out_emb_spec, P()), check_vma=False)
     return fn(table, ids)
 
 

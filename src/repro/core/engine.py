@@ -78,7 +78,6 @@ graph, the rounds and the fabric, freezing each lane with
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import NamedTuple
 
 import jax
@@ -134,11 +133,10 @@ class EngineConfig:
     # to the repro.kernels.engine tile-grid kernels (one grid program = one
     # tile, shard resident in VMEM).  Results are bit-identical by contract
     # (tests/test_backend_pallas.py).  A TaskSpec.backend hint overrides
-    # this per channel.  ``pallas_interpret=True`` (the default) runs the
-    # kernels through the Pallas interpreter so CPU CI executes the same
-    # kernel bodies; set False only on a real TPU (DESIGN.md caveats).
+    # this per channel.  The kernels run through the Pallas interpreter on
+    # the CPU; elsewhere Program.validate rejects them, because the TPU
+    # compiler refuses their bodies (repro.kernels.engine.TPU_REFUSALS).
     backend: str = "xla"     # "xla" | "pallas"
-    pallas_interpret: bool = True
     # ``pallas_fuse=True`` (the default) runs each channel leg whose
     # channels all resolved to "pallas" as ONE pallas_call — the whole
     # per-tile stage (frontier pop, FIFO turn, spill re-queue, remainder
@@ -148,7 +146,7 @@ class EngineConfig:
     # leg plus XLA glue); both are bit-identical to "xla".
     # ``pallas_pad_lanes`` pads every fused-leg operand block to the TPU's
     # (8, 128) sublane x lane f32 tile (sliced back inside the body) so
-    # the non-interpret path lands aligned blocks; value-neutral.
+    # a compiled kernel lands aligned blocks; value-neutral.
     # ``Stats.launches`` counts the pallas_call dispatches per round.
     pallas_fuse: bool = True
     pallas_pad_lanes: bool = False
@@ -505,7 +503,6 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
 
         def fused_stage(me, *args):
             return fused_leg_call(stage, me, *args,
-                                  interpret=cfg.pallas_interpret,
                                   pad_lanes=cfg.pallas_pad_lanes)
         return fused_stage
 
@@ -560,8 +557,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
         q = st.queues[i]
         if chans[i].queued:
             if cx.backend == "pallas":
-                turn = fifo_turn if cx.fused else functools.partial(
-                    queue_push_pop, interpret=cfg.pallas_interpret)
+                turn = fifo_turn if cx.fused else queue_push_pop
                 taken, tvalid, qdata, qcount, d0 = turn(
                     q.data, q.count, rows, valid, pop_i, pops[i])
                 q = Queue(qdata, qcount)
@@ -582,8 +578,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
             if cx.backend == "pallas":
                 none = jnp.zeros((1,), bool)
                 pad = jnp.zeros((1, q.data.shape[1]), jnp.int32)
-                turn = fifo_turn if cx.fused else functools.partial(
-                    queue_push_pop, interpret=cfg.pallas_interpret)
+                turn = fifo_turn if cx.fused else queue_push_pop
                 replay, rvalid, qdata, qcount, _ = turn(
                     q.data, q.count, pad, none, pop_i, pops[i])
                 q = Queue(qdata, qcount)
@@ -853,9 +848,12 @@ def run_engine(comm, cfg: EngineConfig, alg, shard: GraphShard,
 
     ``alg`` is an AlgSpec (compiled via ``classic_program``) or any
     :class:`repro.core.program.Program`.  Returns ``(state, stats,
-    trace)`` — ``trace`` is the captured :class:`repro.trace.TraceBuf`
-    ring when ``cfg.trace``, ``None`` otherwise (the trace-off carry is
-    an empty pytree: byte-identical to a build without the recorder).
+    trace, pending)`` — ``trace`` is the captured
+    :class:`repro.trace.TraceBuf` ring when ``cfg.trace``, ``None``
+    otherwise (the trace-off carry is an empty pytree: byte-identical to
+    a build without the recorder); ``pending`` is the global work left
+    when the loop stopped, nonzero only when ``max_rounds`` cut the run
+    (the host drivers raise on it, :func:`check_finished`).
     """
     prog = as_program(alg)
     prog.validate(cfg, comm.size, e_chunk, v_chunk)
@@ -874,9 +872,21 @@ def run_engine(comm, cfg: EngineConfig, alg, shard: GraphShard,
 
     pending0 = comm.to_global(comm.psum(comm.run(_pending, st)))
     zf = jnp.zeros((), jnp.float32)
-    st, stats, _, tbuf, _, _ = jax.lax.while_loop(
+    st, stats, _, tbuf, pending, _ = jax.lax.while_loop(
         cond, body,
         (st, Stats.zero(net.num_links, net.max_hops, len(prog.channels),
                         net.max_die_crossings),
          (zf, zf), tbuf0, pending0, jnp.int32(0)))
-    return st, stats, (tbuf if cfg.trace else None)
+    return st, stats, (tbuf if cfg.trace else None), pending
+
+
+def check_finished(cfg: EngineConfig, left, what: str, unit: str = "tasks"):
+    """Raise when a run stopped at ``cfg.max_rounds`` with ``left`` work
+    (pending tasks, or unfinished query lanes) still to do: its values
+    are partial, and returning them as a result would hide the cut."""
+    left = int(left)
+    if left:
+        raise RuntimeError(
+            f"{what} stopped at max_rounds={cfg.max_rounds} with {left} "
+            f"{unit} left: its values are partial (raise "
+            f"EngineConfig.max_rounds)")

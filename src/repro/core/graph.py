@@ -60,11 +60,13 @@ class CSRGraph:
         if val is None:
             val = np.ones(len(src), np.float32)
         if dedup and len(src):
+            # unique keys come back sorted by (src, dst): already CSR order
             key = src.astype(np.int64) * n + dst.astype(np.int64)
             _, idx = np.unique(key, return_index=True)
             src, dst, val = src[idx], dst[idx], val[idx]
-        order = np.lexsort((dst, src))
-        src, dst, val = src[order], dst[order], val[order]
+        else:
+            order = np.lexsort((dst, src))
+            src, dst, val = src[order], dst[order], val[order]
         counts = np.bincount(src, minlength=n)
         ptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
         return CSRGraph(ptr, dst.astype(np.int64), val.astype(np.float32))

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import shard_map_compat
 from repro.core.queues import occurrence_index
 from repro.parallel.sharding import ParamSpec, current_mesh, current_rules
 
@@ -291,10 +290,10 @@ def moe_block(params, x, *, E: int, k: int, ff: int, mlp: str,
                 pspec[key] = P(model_axis, ffspec, None)
             else:
                 pspec[key] = P(model_axis, None, ffspec)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             body2, mesh=mesh,
             in_specs=(pspec, P(None, None, None)),
-            out_specs=(P(None, None, None), P(), P()))
+            out_specs=(P(None, None, None), P(), P()), check_vma=False)
         return fn(params, x)
 
     # drop non-divisible shardings (e.g. batch=1 long-context decode)
@@ -323,10 +322,10 @@ def moe_block(params, x, *, E: int, k: int, ff: int, mlp: str,
     pspec = {key: P(model_axis, None, None) for key in params
              if key != "router"}
     pspec["router"] = P(None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P(bspec, sspec, None)),
-        out_specs=(P(bspec, sspec, None), P(), P()))
+        out_specs=(P(bspec, sspec, None), P(), P()), check_vma=False)
     return fn(params, x)
 
 

@@ -51,8 +51,8 @@ import numpy as np
 from repro.core.queues import f2i, i2f
 from repro.kernels.engine import (edge_scan_gather, edge_scan_stream,
                                   fold_scatter, frontier_pop, frontier_take,
-                                  scatter_body, segment_gather,
-                                  segment_stream)
+                                  require_runnable, scatter_body,
+                                  segment_gather, segment_stream)
 from repro.mem import check_alloc, check_budgets, resolve_window
 
 INF = jnp.float32(np.finfo(np.float32).max)
@@ -90,10 +90,6 @@ class Ctx(NamedTuple):
     fused: bool = False
     edge_space: str = "vmem"
     hbm_window: int = 0
-
-
-def _interpret(ctx: Ctx) -> bool:
-    return getattr(ctx.cfg, "pallas_interpret", True)
 
 
 # --------------------------------------------------------------------------
@@ -324,7 +320,13 @@ class Program:
         declared buffer footprint of each memory space must fit its
         capacity (:func:`repro.mem.check_budgets`), replacing what would
         otherwise surface as an opaque allocation failure mid-trace with
-        a config-time error naming the offending buffer and space."""
+        a config-time error naming the offending buffer and space.
+
+        A channel on the pallas backend is also checked against the
+        platform: off the CPU the kernels would have to compile, which
+        the TPU compiler refuses today, so that is an error here too."""
+        if any(ch.resolve_backend(cfg) == "pallas" for ch in self.channels):
+            require_runnable()
         for ch, need in zip(self.channels, self.min_caps(cfg, T)):
             cap = ch.qcap(cfg)
             assert cap >= need, (
@@ -421,15 +423,19 @@ def sized_cfg(cfg, program: Program, T: int):
 def take_first_k(mask: jax.Array, k: jax.Array, k_max: int):
     """Indices of the first ``min(k, popcount)`` set bits, FIFO by position.
 
+    The i-th taken bit is where the inclusive popcount prefix first
+    reaches i+1, so a cumsum and a ``k_max``-query binary search find
+    them without sorting the whole bitmap.  Invalid slots hold clamped
+    positions (don't-cares, masked by ``valid`` downstream).
+
     Returns (idx (k_max,) i32, valid (k_max,) bool, cleared_mask)."""
     n = mask.shape[0]
-    ar = jnp.arange(n, dtype=jnp.int32)
-    rank = jnp.cumsum(mask.astype(jnp.int32)) - mask.astype(jnp.int32)
-    take = mask & (rank < k)
-    key = jnp.where(take, rank, jnp.int32(n) + ar)
-    order = jnp.argsort(key)[:k_max]
-    valid = take[order]
-    return order.astype(jnp.int32), valid, mask & ~take
+    csum = jnp.cumsum(mask.astype(jnp.int32))
+    want = jnp.arange(1, k_max + 1, dtype=jnp.int32)
+    idx = jnp.searchsorted(csum, want, side="left", method="scan_unrolled")
+    valid = want <= jnp.minimum(k, csum[-1])
+    return (jnp.minimum(idx, n - 1).astype(jnp.int32), valid,
+            mask & (csum > k))
 
 
 def frontier_source(payload: Callable) -> Callable:
@@ -448,8 +454,7 @@ def frontier_source(payload: Callable) -> Callable:
                     st.frontier, budget, ctx.cfg.f_pop)
             else:
                 vidx, vvalid, frontier = frontier_pop(
-                    st.frontier, budget, ctx.cfg.f_pop,
-                    interpret=_interpret(ctx))
+                    st.frontier, budget, ctx.cfg.f_pop)
         else:
             vidx, vvalid, frontier = take_first_k(st.frontier, budget,
                                                   ctx.cfg.f_pop)
@@ -480,6 +485,38 @@ def range_split(ctx: Ctx, taken: jax.Array, tvalid: jax.Array):
     return msgs, tvalid, rem, tvalid & (stop < t_end)
 
 
+def segment_windows(edge_dst: jax.Array, edge_val: jax.Array,
+                    local0: jax.Array, max_t2: int):
+    """``(R, max_t2)`` windows ``edge_*[local0 + j]`` of the tile's shard,
+    exact wherever ``local0 + j`` is inside it (every lane of a segment
+    that ``range_split`` bounded at the chunk border).
+
+    Each message's segment is contiguous, so it is read as one slice
+    rather than ``max_t2`` single-word gathers, which a TPU serves one
+    random HBM access at a time.  A slice that would run past the shard
+    is moved back to end at its last word, and its rows are shifted
+    left again by a barrel shifter (log2(max_t2) selects)."""
+    e_chunk = edge_dst.shape[0]
+    m = min(max_t2, e_chunk)
+    first = jnp.minimum(local0, e_chunk - m)
+    shift = local0 - first                       # in [0, m)
+
+    def windows(arr):
+        win = jax.vmap(
+            lambda s: jax.lax.dynamic_slice_in_dim(arr, s, m))(first)
+        if m < max_t2:  # shard shorter than a segment bound
+            win = jnp.pad(win, ((0, 0), (0, max_t2 - m)), mode="edge")
+        b = 1
+        while b < m:
+            moved = jnp.concatenate(
+                [win[:, b:], jnp.repeat(win[:, -1:], b, axis=1)], axis=1)
+            win = jnp.where(((shift & b) != 0)[:, None], moved, win)
+            b *= 2
+        return win
+
+    return windows(edge_dst), windows(edge_val)
+
+
 def edge_scan(emit_rows: Callable) -> Callable:
     """T2 skeleton: scan the local edge chunk for each received range
     message ``(start, stop, *payload)``.
@@ -491,18 +528,15 @@ def edge_scan(emit_rows: Callable) -> Callable:
 
     def handler(ctx: Ctx, me, sh, st, recv, rv):
         r_start, r_stop = recv[:, 0], recv[:, 1]
-        if ctx.edge_space == "hbm":
-            # HBM-resident shard: both backends consume it through the
-            # double-buffered segment-DMA stream (the xla path runs the
-            # same pure body the fused kernel does — space equivalence
-            # and backend equivalence hold by construction).
-            if ctx.backend == "pallas" and not ctx.fused:
-                nb, w, jvalid = edge_scan_stream(
-                    sh.edge_dst, sh.edge_val, r_start, r_stop, rv,
-                    ctx.cfg.max_t2, ctx.hbm_window,
-                    interpret=_interpret(ctx))
-            else:
+        if ctx.backend == "pallas" and ctx.edge_space == "hbm":
+            # HBM-resident shard: the kernels consume it through the
+            # double-buffered segment-DMA stream.
+            if ctx.fused:  # already inside the leg's single pallas_call
                 nb, w, jvalid = segment_stream(
+                    sh.edge_dst, sh.edge_val, r_start, r_stop, rv,
+                    ctx.cfg.max_t2, ctx.hbm_window)
+            else:
+                nb, w, jvalid = edge_scan_stream(
                     sh.edge_dst, sh.edge_val, r_start, r_stop, rv,
                     ctx.cfg.max_t2, ctx.hbm_window)
         elif ctx.backend == "pallas":
@@ -513,16 +547,18 @@ def edge_scan(emit_rows: Callable) -> Callable:
             else:
                 nb, w, jvalid = edge_scan_gather(
                     sh.edge_dst, sh.edge_val, r_start, r_stop, rv,
-                    ctx.cfg.max_t2, interpret=_interpret(ctx))
+                    ctx.cfg.max_t2)
         else:
+            # Either space: every valid lane reads the shard element the
+            # HBM stream would stage for it (segment_stream's contract), so
+            # the xla path reads each segment directly, as one contiguous
+            # window per message (see segment_windows).
             length = jnp.where(rv, r_stop - r_start, 0)
             local0 = jnp.where(rv, r_start % ctx.e_chunk, 0)
             j = jnp.arange(ctx.cfg.max_t2, dtype=jnp.int32)[None, :]
-            eidx = local0[:, None] + j                  # (R, MAX_T2)
             jvalid = rv[:, None] & (j < length[:, None])
-            eidx_c = jnp.minimum(eidx, ctx.e_chunk - 1)
-            nb = sh.edge_dst[eidx_c]
-            w = sh.edge_val[eidx_c]
+            nb, w = segment_windows(sh.edge_dst, sh.edge_val, local0,
+                                    ctx.cfg.max_t2)
             jvalid = jvalid & (nb >= 0)
         rows, ov = emit_rows(ctx, recv, nb, w, jvalid)
         edges = jvalid.sum(dtype=jnp.int32)
@@ -541,8 +577,7 @@ def scatter_fold(ctx: Ctx, target: jax.Array, lidx: jax.Array,
     if ctx.backend == "pallas":
         if ctx.fused:  # already inside the leg's single pallas_call
             return scatter_body(target, lidx, vals, valid, op)
-        return fold_scatter(target, lidx, vals, valid, op=op,
-                            interpret=_interpret(ctx))
+        return fold_scatter(target, lidx, vals, valid, op=op)
     neutral = INF if op == "min" else jnp.float32(0.0)
     ext = jnp.concatenate([target, jnp.full((1,), neutral, jnp.float32)])
     masked = jnp.where(valid, vals, neutral)

@@ -5,7 +5,8 @@ which the paper implements inside each tile's scratchpad (Section III-E).
 A queue is a pytree ``(data, count)`` where ``data`` is a ``(cap, width)``
 array whose first ``count`` rows are valid, stored in FIFO order and always
 compacted to the front. Every operation is static-shape (jit/scan/while_loop
-safe) and costs O(cap log cap) for the order-preserving compactions.
+safe).  Pushes compact with a sort and pops shift with a slice, so no
+operation scatters or gathers row by row.
 
 All queues store int32; float payloads are bitcast via :func:`f2i`/:func:`i2f`
 so a single dtype flows through the network buffers — mirroring the paper's
@@ -65,53 +66,44 @@ def queue_push(q: Queue, rows: jax.Array, mask: jax.Array) -> tuple[Queue, jax.A
     the counter exists so tests and production monitors can assert the
     backpressure invariant, like the paper's "CQ full" check.
 
+    The masked rows are compacted to the front by a stable sort that
+    carries them as payload, and the compacted block is written at the
+    tail with one slice update: no per-row scatter, which a TPU runs one
+    row at a time.  Only the pushed rows change.
+
     Returns (new_queue, n_dropped).
     """
-    cap = q.data.shape[0]
-    mask = mask.astype(jnp.int32)
-    offs = q.count + jnp.cumsum(mask) - mask  # target slot for each masked row
-    ok = (mask == 1) & (offs < cap)
-    # Scatter into an extended buffer; row `cap` is the trash slot.
-    idx = jnp.where(ok, offs, cap)
-    ext = jnp.concatenate([q.data, jnp.zeros((1, q.data.shape[1]), jnp.int32)], 0)
-    ext = ext.at[idx].set(rows)
-    n_push = ok.sum(dtype=jnp.int32)
-    n_drop = mask.sum(dtype=jnp.int32) - n_push
-    return Queue(ext[:cap], q.count + n_push), n_drop
-
-
-def queue_take(q: Queue, take_mask: jax.Array) -> tuple[jax.Array, jax.Array, Queue]:
-    """Remove entries selected by ``take_mask`` (bool over all cap slots).
-
-    Only slots < count participate. Returns ``(taken_rows, taken_valid, q')``
-    where taken rows are compacted to the front of a (cap, width) buffer in
-    FIFO order and the remaining queue is re-compacted, order preserved.
-    """
-    cap = q.data.shape[0]
-    ar = jnp.arange(cap, dtype=jnp.int32)
-    valid = ar < q.count
-    take = take_mask & valid
-    keep = valid & ~take
-    big = jnp.int32(cap)
-    # Unique keys -> deterministic order-preserving partition.
-    perm_t = jnp.argsort(jnp.where(take, ar, big + ar))
-    n_t = take.sum(dtype=jnp.int32)
-    taken = q.data[perm_t]
-    taken_valid = ar < n_t
-    perm_k = jnp.argsort(jnp.where(keep, ar, big + ar))
-    kept = q.data[perm_k]
-    n_k = keep.sum(dtype=jnp.int32)
-    return taken, taken_valid, Queue(kept, n_k)
+    cap, w = q.data.shape
+    n = rows.shape[0]
+    m = mask.astype(jnp.int32)
+    n_valid = m.sum(dtype=jnp.int32)
+    n_push = jnp.minimum(n_valid, cap - q.count)
+    cols = jax.lax.sort((1 - m,) + tuple(rows[:, j] for j in range(w)),
+                        num_keys=1, is_stable=True)[1:]
+    ext = jnp.concatenate([q.data, jnp.zeros((n, w), jnp.int32)], 0)
+    ext = jax.lax.dynamic_update_slice_in_dim(
+        ext, jnp.stack(cols, axis=1), q.count, axis=0)[:cap]
+    slot = jnp.arange(cap, dtype=jnp.int32)[:, None]
+    pushed = (slot >= q.count) & (slot < q.count + n_push)
+    return (Queue(jnp.where(pushed, ext, q.data), q.count + n_push),
+            n_valid - n_push)
 
 
 def queue_take_front(q: Queue, n: jax.Array, max_n: int) -> tuple[jax.Array, jax.Array, Queue]:
     """Pop the first ``min(n, count)`` entries (FIFO). ``max_n`` is the static
-    bound on n; the returned buffer has shape (max_n, width)."""
-    cap = q.data.shape[0]
-    ar = jnp.arange(cap, dtype=jnp.int32)
-    n = jnp.minimum(n, q.count).astype(jnp.int32)
-    taken_full, tv_full, q2 = queue_take(q, ar < n)
-    return taken_full[:max_n], tv_full[:max_n], q2
+    bound on n; the returned buffer has shape (min(max_n, cap), width).
+
+    The queue is compacted to the front, so the pop is a slice and the
+    rest moves down by one dynamic slice (rows at or past the new count
+    are don't-cares)."""
+    cap, w = q.data.shape
+    eff = min(max_n, cap)
+    n = jnp.minimum(jnp.minimum(n, q.count), eff).astype(jnp.int32)
+    taken = q.data[:eff]
+    taken_valid = jnp.arange(eff, dtype=jnp.int32) < n
+    ext = jnp.concatenate([q.data, jnp.zeros((eff, w), jnp.int32)], 0)
+    rest = jax.lax.dynamic_slice_in_dim(ext, n, cap, axis=0)
+    return taken, taken_valid, Queue(rest, q.count - n)
 
 
 def occurrence_index(dest: jax.Array, valid: jax.Array, num_dest: int) -> jax.Array:

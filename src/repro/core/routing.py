@@ -25,7 +25,7 @@ dimension-ordered per-axis exchanges with per-link backpressure.
    wormhole transfer), and
 4. returns the received messages plus the spilled ones for local re-queueing.
 
-Slot claiming is FIFO per destination (``occurrence_index``), matching the
+Slot claiming is FIFO per destination (:func:`bin_by_owner`), matching the
 in-order per-channel delivery of the paper's wormhole NoC.
 """
 from __future__ import annotations
@@ -35,7 +35,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.queues import occurrence_index, histogram
+from repro.core.queues import histogram
 
 EMPTY = jnp.int32(-1)  # head-flit value marking an empty network slot
 
@@ -66,16 +66,39 @@ def bin_by_owner(msgs, valid, dest, num_shards, capacity):
     ``d``; empty slots have head flit -1.  FIFO order within each destination
     is preserved; messages beyond ``capacity`` for a destination are returned
     as spill (masked in place).
+
+    One stable sort by destination carries the messages as payload, so
+    each destination's messages sit contiguously in FIFO order: slot
+    ``(d, c)`` is the ``c``-th of them, read by one slice per destination.
+    A second sort brings the fit flags back to message order.  No row is
+    scattered or gathered on its own (a TPU runs those one row at a time).
     """
     n, w = msgs.shape
-    occ = occurrence_index(dest, valid, num_shards)  # >= n for invalid rows
-    fits = valid & (occ < capacity)
-    slot = jnp.where(fits, dest * capacity + occ, num_shards * capacity)
-    buf = jnp.full((num_shards * capacity + 1, w), EMPTY, jnp.int32)
-    buf = buf.at[slot].set(msgs)
+    ar = jnp.arange(n, dtype=jnp.int32)
+    d = jnp.where(valid, dest, num_shards).astype(jnp.int32)  # invalid last
+    d_s, ar_s, *cols = jax.lax.sort(
+        (d, ar) + tuple(msgs[:, j] for j in range(w)), num_keys=1,
+        is_stable=True)
+    # FIFO rank inside each destination's run of the sorted order
+    new_grp = jnp.concatenate([jnp.ones((1,), bool), d_s[1:] != d_s[:-1]])
+    occ = ar - jax.lax.associative_scan(jnp.maximum,
+                                        jnp.where(new_grp, ar, 0))
+    fits_s = (d_s < num_shards) & (occ < capacity)
+    # where each destination's run starts, and how many of it fit
+    start = jnp.searchsorted(d_s, jnp.arange(num_shards + 1, dtype=jnp.int32),
+                             side="left", method="scan_unrolled")
+    start = start.astype(jnp.int32)
+    fill = jnp.minimum(start[1:] - start[:-1], capacity)
+    sorted_msgs = jnp.concatenate(
+        [jnp.stack(cols, axis=1), jnp.full((capacity, w), EMPTY, jnp.int32)])
+    blocks = jax.vmap(lambda s0: jax.lax.dynamic_slice_in_dim(
+        sorted_msgs, s0, capacity))(start[:-1])      # (T, capacity, W)
+    live = jnp.arange(capacity, dtype=jnp.int32)[None, :] < fill[:, None]
+    buf = jnp.where(live[..., None], blocks, EMPTY)
+    _, fits = jax.lax.sort((ar_s, fits_s), num_keys=1)
     spill_valid = valid & ~fits
-    n_sent = fits.sum(dtype=jnp.int32)
-    return buf[:-1], msgs, spill_valid, n_sent
+    n_sent = fits_s.sum(dtype=jnp.int32)
+    return buf.reshape(num_shards * capacity, w), msgs, spill_valid, n_sent
 
 
 def route_tasks(comm, msgs: jax.Array, valid: jax.Array, dest: jax.Array,

@@ -43,14 +43,18 @@ brackets its round with :func:`..launches.tally`, and the per-round total
 surfaces as ``Stats.launches`` (fig11's ``launches_per_round`` column —
 one launch per leg fused, vs 4+ standalone launches plus XLA glue before).
 
-All kernels default to ``interpret=True`` so CPU CI executes the very same
-kernel bodies the TPU path compiles, and every kernel is **bit-identical**
-to its XLA twin in ``core/program.py`` / ``core/queues.py`` (the backend
-equivalence contract ``tests/test_backend_pallas.py`` +
-``tests/test_fused_leg.py`` enforce).  ``pad_lanes=True`` additionally
-pads every fused-leg operand block out to the TPU's (8, 128)
-sublane x lane f32 tile (sliced back to logical shape inside the body), so
-the same harness lands aligned blocks when ``pallas_interpret=False``.
+Interpret mode is decided in one place, from the platform
+(:func:`interpret_mode`): the kernels run through the Pallas interpreter on
+the CPU, so CPU CI executes the very same kernel bodies, and are compiled
+everywhere else.  The TPU compiler refuses the bodies today
+(:data:`TPU_REFUSALS`), so on a TPU ``Program.validate`` rejects a
+``backend="pallas"`` run at config time instead of interpreting it.  Every
+kernel is **bit-identical** to its XLA twin in ``core/program.py`` /
+``core/queues.py`` (the backend equivalence contract
+``tests/test_backend_pallas.py`` + ``tests/test_fused_leg.py`` enforce).
+``pad_lanes=True`` additionally pads every fused-leg operand block out to
+the TPU's (8, 128) sublane x lane f32 tile (sliced back to logical shape
+inside the body), so the same harness lands aligned blocks when compiled.
 """
 from __future__ import annotations
 
@@ -67,6 +71,36 @@ from repro.kernels.engine.launches import record
 # consts); must equal core.program.INF so the fold's neutral element is the
 # same "unreached" sentinel the XLA legs use.
 _INF = 3.4028234663852886e38
+
+# What the TPU compiler refuses in the pure bodies below, found by
+# compiling each standalone kernel for a described v5e (the fused leg
+# composes the same bodies).
+TPU_REFUSALS = (
+    "frontier_pop, queue_push_pop: unimplemented primitive cumsum "
+    "(frontier_take, fifo_turn, queue_append)",
+    "fold_scatter: unimplemented primitives scatter-min, scatter-add "
+    "(scatter_body)",
+    "edge_scan_gather, edge_scan_stream: only 2D gather is supported "
+    "(segment_gather, segment_stream)",
+)
+
+
+def interpret_mode() -> bool:
+    """Whether the engine kernels run through the Pallas interpreter: on
+    the CPU, and nowhere else."""
+    return jax.default_backend() == "cpu"
+
+
+def require_runnable():
+    """Raise unless the engine kernels can run on this platform: they are
+    interpreted on the CPU, and compiled elsewhere, where the TPU compiler
+    refuses their bodies (:data:`TPU_REFUSALS`)."""
+    if not interpret_mode():
+        raise NotImplementedError(
+            f"backend='pallas' cannot run on {jax.default_backend()!r}: the "
+            "engine kernels are interpreted only on the CPU, and the TPU "
+            "compiler refuses them (" + "; ".join(TPU_REFUSALS)
+            + "). Use backend='xla'.")
 
 
 # ==========================================================================
@@ -238,8 +272,7 @@ def _lane_pad(shape: tuple) -> tuple:
     return tuple(s)
 
 
-def fused_leg_call(fn, *operands, interpret: bool = True,
-                   pad_lanes: bool = False):
+def fused_leg_call(fn, *operands, pad_lanes: bool = False):
     """Run the per-tile stage ``fn(*operands)`` as ONE Pallas launch.
 
     ``fn`` is a pure pytree -> pytree function (an engine channel leg:
@@ -321,7 +354,7 @@ def fused_leg_call(fn, *operands, interpret: bool = True,
         for s, d in out_specs if live(s))
     record()
     raw = pl.pallas_call(kernel, out_shape=out_shape,
-                         interpret=interpret)(*call_ins)
+                         interpret=interpret_mode())(*call_ins)
     if not isinstance(raw, (tuple, list)):
         raw = (raw,)
     it = iter(raw)
@@ -356,20 +389,19 @@ def _frontier_pop_kernel(k_ref, mask_ref, idx_ref, valid_ref, rem_ref):
     rem_ref[...] = rem
 
 
-@functools.partial(jax.jit, static_argnames=("k_max", "interpret"))
-def _frontier_pop(mask, k, k_max, interpret):
+@functools.partial(jax.jit, static_argnames=("k_max",))
+def _frontier_pop(mask, k, k_max):
     n = mask.shape[0]
     return pl.pallas_call(
         _frontier_pop_kernel,
         out_shape=(jax.ShapeDtypeStruct((k_max,), jnp.int32),
                    jax.ShapeDtypeStruct((k_max,), jnp.bool_),
                    jax.ShapeDtypeStruct((n,), jnp.bool_)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(jnp.asarray(k, jnp.int32).reshape(1), mask)
 
 
-def frontier_pop(mask: jax.Array, k: jax.Array, k_max: int,
-                 interpret: bool = True):
+def frontier_pop(mask: jax.Array, k: jax.Array, k_max: int):
     """Pop the first ``min(k, popcount)`` set bits of the tile's frontier
     bitmap, FIFO by position — the Pallas twin of
     :func:`repro.core.program.take_first_k`.
@@ -380,7 +412,7 @@ def frontier_pop(mask: jax.Array, k: jax.Array, k_max: int,
     there); both are don't-cares masked by ``valid`` everywhere downstream.
     """
     record()
-    return _frontier_pop(mask, k, k_max, interpret)
+    return _frontier_pop(mask, k, k_max)
 
 
 # --------------------------------------------------------------------------
@@ -400,8 +432,8 @@ def _queue_push_pop_kernel(n_ref, data_ref, count_ref, rows_ref, pvalid_ref,
     drops_ref[0] = drops
 
 
-@functools.partial(jax.jit, static_argnames=("max_n", "interpret"))
-def _queue_push_pop(data, count, rows, valid, n, max_n, interpret):
+@functools.partial(jax.jit, static_argnames=("max_n",))
+def _queue_push_pop(data, count, rows, valid, n, max_n):
     return pl.pallas_call(
         _queue_push_pop_kernel,
         out_shape=(jax.ShapeDtypeStruct((max_n, data.shape[1]), jnp.int32),
@@ -409,14 +441,13 @@ def _queue_push_pop(data, count, rows, valid, n, max_n, interpret):
                    jax.ShapeDtypeStruct(data.shape, jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(jnp.asarray(n, jnp.int32).reshape(1), data,
       jnp.asarray(count, jnp.int32).reshape(1), rows, valid)
 
 
 def queue_push_pop(data: jax.Array, count: jax.Array, rows: jax.Array,
-                   valid: jax.Array, n: jax.Array, max_n: int,
-                   interpret: bool = True):
+                   valid: jax.Array, n: jax.Array, max_n: int):
     """One fused FIFO turn: ``queue_push(rows[valid])`` then
     ``queue_take_front(min(n, count'))`` in a single kernel.
 
@@ -439,7 +470,7 @@ def queue_push_pop(data: jax.Array, count: jax.Array, rows: jax.Array,
     assert max_n <= cap, f"pop budget bound {max_n} > queue capacity {cap}"
     record()
     taken, tvalid, ndata, ncount, drops = _queue_push_pop(
-        data, count, rows, valid, n, max_n, interpret)
+        data, count, rows, valid, n, max_n)
     return taken, tvalid, ndata, ncount[0], drops[0]
 
 
@@ -457,22 +488,21 @@ def _edge_scan_kernel(edge_dst_ref, edge_val_ref, start_ref, stop_ref,
     jvalid_ref[...] = jvalid
 
 
-@functools.partial(jax.jit, static_argnames=("max_t2", "interpret"))
-def _edge_scan_gather(edge_dst, edge_val, start, stop, rv, max_t2,
-                      interpret):
+@functools.partial(jax.jit, static_argnames=("max_t2",))
+def _edge_scan_gather(edge_dst, edge_val, start, stop, rv, max_t2):
     r = start.shape[0]
     return pl.pallas_call(
         _edge_scan_kernel,
         out_shape=(jax.ShapeDtypeStruct((r, max_t2), jnp.int32),
                    jax.ShapeDtypeStruct((r, max_t2), jnp.float32),
                    jax.ShapeDtypeStruct((r, max_t2), jnp.bool_)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(edge_dst, edge_val, start, stop, rv)
 
 
 def edge_scan_gather(edge_dst: jax.Array, edge_val: jax.Array,
                      start: jax.Array, stop: jax.Array, rv: jax.Array,
-                     max_t2: int, interpret: bool = True):
+                     max_t2: int):
     """The T2 segment gather: for each delivered range message, read its
     ``[start, stop)`` slice (bounded by MAX_T2 upstream) out of the tile's
     VMEM-resident edge shard.
@@ -485,8 +515,7 @@ def edge_scan_gather(edge_dst: jax.Array, edge_val: jax.Array,
     :func:`repro.core.program.edge_scan`.
     """
     record()
-    return _edge_scan_gather(edge_dst, edge_val, start, stop, rv, max_t2,
-                             interpret)
+    return _edge_scan_gather(edge_dst, edge_val, start, stop, rv, max_t2)
 
 
 # --------------------------------------------------------------------------
@@ -503,22 +532,21 @@ def _edge_stream_kernel(edge_dst_ref, edge_val_ref, start_ref, stop_ref,
     jvalid_ref[...] = jvalid
 
 
-@functools.partial(jax.jit, static_argnames=("max_t2", "window", "interpret"))
-def _edge_scan_stream(edge_dst, edge_val, start, stop, rv, max_t2, window,
-                      interpret):
+@functools.partial(jax.jit, static_argnames=("max_t2", "window"))
+def _edge_scan_stream(edge_dst, edge_val, start, stop, rv, max_t2, window):
     r = start.shape[0]
     return pl.pallas_call(
         functools.partial(_edge_stream_kernel, window=window),
         out_shape=(jax.ShapeDtypeStruct((r, max_t2), jnp.int32),
                    jax.ShapeDtypeStruct((r, max_t2), jnp.float32),
                    jax.ShapeDtypeStruct((r, max_t2), jnp.bool_)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(edge_dst, edge_val, start, stop, rv)
 
 
 def edge_scan_stream(edge_dst: jax.Array, edge_val: jax.Array,
                      start: jax.Array, stop: jax.Array, rv: jax.Array,
-                     max_t2: int, window: int, interpret: bool = True):
+                     max_t2: int, window: int):
     """The T2 segment scan when the tile's edge shard is declared in HBM:
     each delivered range message stages its two covering DMA windows into
     VMEM (the double buffer) and gathers its segment from the staging
@@ -532,7 +560,7 @@ def edge_scan_stream(edge_dst: jax.Array, edge_val: jax.Array,
     """
     record()
     return _edge_scan_stream(edge_dst, edge_val, start, stop, rv, max_t2,
-                             window, interpret)
+                             window)
 
 
 # --------------------------------------------------------------------------
@@ -545,17 +573,17 @@ def _fold_scatter_kernel(target_ref, lidx_ref, vals_ref, valid_ref, out_ref,
                                 vals_ref[...], valid_ref[...], op)
 
 
-@functools.partial(jax.jit, static_argnames=("op", "interpret"))
-def _fold_scatter(target, lidx, vals, valid, op, interpret):
+@functools.partial(jax.jit, static_argnames=("op",))
+def _fold_scatter(target, lidx, vals, valid, op):
     return pl.pallas_call(
         functools.partial(_fold_scatter_kernel, op=op),
         out_shape=jax.ShapeDtypeStruct(target.shape, jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(target, lidx, vals, valid)
 
 
 def fold_scatter(target: jax.Array, lidx: jax.Array, vals: jax.Array,
-                 valid: jax.Array, op: str = "min", interpret: bool = True):
+                 valid: jax.Array, op: str = "min"):
     """The T3 fold: drain a delivered CQ buffer into the tile's owned
     ``(v_chunk,)`` slice — scatter-min for relaxations, scatter-add for
     accumulations.  Atomic-free: all writes land in this tile's own shard
@@ -568,4 +596,4 @@ def fold_scatter(target: jax.Array, lidx: jax.Array, vals: jax.Array,
     """
     assert op in ("min", "add"), op
     record()
-    return _fold_scatter(target, lidx, vals, valid, op, interpret)
+    return _fold_scatter(target, lidx, vals, valid, op)

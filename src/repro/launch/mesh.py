@@ -8,26 +8,18 @@ from __future__ import annotations
 import jax
 
 
-def auto_mesh(shape, axes):
-    """make_mesh across jax versions: AxisType.Auto where it exists (>=0.5),
-    plain mesh otherwise (older jax is Auto-only anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod; the multi-pod mesh adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small CPU mesh for tests/examples (requires forced host devices)."""
-    return auto_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def rules_for(mesh, kind: str = "train"):

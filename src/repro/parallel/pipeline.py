@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import shard_map_compat
 
 
 def pipeline_apply(stage_fn, params, x, *, mesh, axis: str, n_micro: int):
@@ -60,10 +59,10 @@ def pipeline_apply(stage_fn, params, x, *, mesh, axis: str, n_micro: int):
         return outs
 
     pspecs = jax.tree.map(lambda _: P(axis), params)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return fn(params, x)
 
 

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -105,8 +104,8 @@ def ring_attention(q, k, v, *, mesh, model_axis: str = "model",
             Bl, S_loc, H, hd).astype(qb.dtype)
 
     spec_q = P(bspec, model_axis, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_q, spec_q, spec_q),
-        out_specs=spec_q)
+        out_specs=spec_q, check_vma=False)
     return fn(q, k, v)

@@ -36,12 +36,14 @@ def main() -> int:
     ap.add_argument("--per-query", action="store_true")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.dalorex_graph import get_workload
     from repro.core import algorithms as alg
     from repro.core.engine import EngineConfig
     from repro.core.graph import CSRGraph, rmat_edges
     from repro.serve import Frontend
 
+    enable_compile_cache()
     wl = get_workload(args.preset)
     n, src, dst, val = rmat_edges(wl.scale, edge_factor=wl.edge_factor,
                                   seed=0)

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.comm import LocalComm
-from repro.core.engine import EngineConfig, EngineState
+from repro.core.engine import EngineConfig, EngineState, check_finished
 from repro.core.graph import PartitionedGraph
 from repro.core.program import CLASSIC, as_program
 from repro.core.queues import Queue, queue_clear
@@ -388,6 +388,12 @@ class Frontend:
                                         pg.v_chunk, shard, carry)
             now += float(carry.clock) - prev_clock
             pending = np.asarray(carry.pending)
+            # a segment ends when a lane finishes (halt), when every lane
+            # is idle, or when max_rounds cuts it
+            if not bool(carry.halt):
+                check_finished(cfg, (pending > 0).sum(),
+                               f"continuous {self.app} serving",
+                               "query lanes")
             lane_rounds = np.asarray(carry.stats.rounds)
             lane_edges = np.asarray(carry.stats.edges_scanned)
             lane_drops = np.asarray(carry.stats.drops)

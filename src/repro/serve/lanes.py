@@ -57,10 +57,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.comm import AxisComm, LocalComm, shard_map_compat
+from repro.core.comm import AxisComm, LocalComm
 from repro.core.engine import (EngineConfig, EngineState, GraphShard, Stats,
-                               init_state, lane_select, make_round,
-                               pending_work)
+                               check_finished, init_state, lane_select,
+                               make_round, pending_work)
 from repro.core.graph import PartitionedGraph
 from repro.core.program import CLASSIC, as_program
 from repro.noc import make_network
@@ -259,10 +259,11 @@ def spmd_lanes_call(pg: PartitionedGraph, prog, cfg: EngineConfig, value,
     # lane-led trace rings hold only globals — replicated, like Stats
     trace_spec = jax.tree.map(lambda _: P(), zero_trace(cfg, T, prog)) \
         if cfg.trace else ()
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec2,) * 4 + (spec3,) * 3,
-        out_specs=(spec3, stats_spec, P(), P(), P(), P(), P(), trace_spec))
+        out_specs=(spec3, stats_spec, P(), P(), P(), P(), P(), trace_spec),
+        check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, spec2)) for a in
             (pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val)]
     args += [jax.device_put(a, NamedSharding(mesh, spec3)) for a in
@@ -334,7 +335,8 @@ def multi_source(pg: PartitionedGraph, app: str, sources,
 
     ``mesh=None`` runs the LocalComm emulation; a mesh runs shard_map SPMD.
     Per-query results are bit-identical to solo :func:`repro.core.
-    algorithms.bfs` / ``sssp`` runs at the same ``cfg``.
+    algorithms.bfs` / ``sssp`` runs at the same ``cfg``.  A batch that
+    ``cfg.max_rounds`` cut before every lane finished raises.
     """
     if app not in ("bfs", "sssp"):
         raise ValueError(f"multi_source serves point queries (bfs/sssp), "
@@ -358,6 +360,8 @@ def multi_source(pg: PartitionedGraph, app: str, sources,
          trace) = spmd_lanes_call(pg, alg_spec, cfg, value, frontier, mesh)
         if not cfg.trace:
             trace = None
+    check_finished(cfg, (np.asarray(done_round) < 0).sum(),
+                   f"{app} batch of {len(sources)}", "query lanes")
     B = len(sources)
     flat = np.asarray(vals).reshape(B, -1)
     values = flat[:, np.asarray(pg.place)].astype(np.float64)

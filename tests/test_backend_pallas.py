@@ -236,13 +236,34 @@ def test_per_channel_backend_hint_mixes_backends(g, pg):
     root = int(np.argmax(g.ptr[1:] - g.ptr[:-1]))
     from repro.core.algorithms import init_min_state, local_engine_call
     value, frontier = init_min_state(pg, [root])
-    vx, _, sx, _ = local_engine_call(pg, prog, small_cfg(backend="xla"),
-                                     value, frontier)
-    vm, _, sm, _ = local_engine_call(pg, pinned,
-                                     small_cfg(backend="pallas"),
-                                     value, frontier)
+    vx, _, sx, _, _ = local_engine_call(pg, prog, small_cfg(backend="xla"),
+                                        value, frontier)
+    vm, _, sm, _, _ = local_engine_call(pg, pinned,
+                                        small_cfg(backend="pallas"),
+                                        value, frontier)
     np.testing.assert_array_equal(np.asarray(vx), np.asarray(vm))
     assert_stats_identical(sx, sm, "(mixed backends)")
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_interpret_mode_follows_the_platform(pg, platform, monkeypatch):
+    """The interpreter runs only on the CPU.  Off it, a pallas-backed
+    program is refused at config time, naming what the TPU compiler
+    refuses, while the xla backend validates as before."""
+    from repro.core.program import as_program
+    from repro.kernels.engine import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert interpret_mode() == (platform == "cpu")
+    prog = as_program(alg.BFS)
+    prog.validate(small_cfg(backend="xla"), pg.T, pg.e_chunk, pg.v_chunk)
+    if platform == "cpu":
+        prog.validate(small_cfg(backend="pallas"), pg.T, pg.e_chunk,
+                      pg.v_chunk)
+        return
+    with pytest.raises(NotImplementedError,
+                       match="(?s)'tpu'.*cumsum.*scatter-min.*2D gather"):
+        prog.validate(small_cfg(backend="pallas"), pg.T, pg.e_chunk,
+                      pg.v_chunk)
 
 
 # --------------------------------------------------------------------------

@@ -106,3 +106,58 @@ def test_vertex_aligned_edges_correct(g):
     root = pick_root(g)
     res = alg.bfs(pg3, root, small_cfg())
     np.testing.assert_array_equal(res.values, ref.bfs_ref(g, root))
+
+
+def _naive_bfs(g, root):
+    """The per-edge loop BFS that ``bfs_ref`` replaced, kept as its
+    reference: one frontier vertex and one edge at a time."""
+    dist = np.full(g.num_vertices, np.inf)
+    dist[root] = 0
+    frontier, d = [root], 0
+    while frontier:
+        nxt = []
+        for v in frontier:
+            for e in range(g.ptr[v], g.ptr[v + 1]):
+                u = g.dst[e]
+                if dist[u] == np.inf:
+                    dist[u] = d + 1
+                    nxt.append(u)
+        frontier, d = nxt, d + 1
+    return dist
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_bfs_ref_matches_naive_bfs(seed):
+    """The level-synchronous oracle equals the loop BFS on random small
+    graphs, directed and symmetrized, from roots of every kind
+    (isolated, high-degree, arbitrary)."""
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(2, 200))
+    m = int(rng.integers(0, 4 * n))
+    src, dst = rng.integers(0, n, m), rng.integers(0, n, m)
+    g = CSRGraph.from_edges(n, src, dst)
+    for graph in (g, alg.symmetrize(g)):
+        deg = graph.ptr[1:] - graph.ptr[:-1]
+        for root in {0, int(np.argmax(deg)), int(rng.integers(0, n))}:
+            np.testing.assert_array_equal(ref.bfs_ref(graph, root),
+                                          _naive_bfs(graph, root))
+
+
+@pytest.mark.parametrize("driver", ["algorithms", "multi_source",
+                                    "frontend_continuous"])
+def test_cut_run_raises(g, pg, driver):
+    """A run that max_rounds stops with work still pending is an error in
+    every host driver, never a partial result."""
+    from repro.serve import Frontend, multi_source
+    root = pick_root(g)
+    cfg = small_cfg(max_rounds=3)
+    with pytest.raises(RuntimeError, match="max_rounds=3"):
+        if driver == "algorithms":
+            alg.bfs(pg, root, cfg)
+        elif driver == "multi_source":
+            multi_source(pg, "bfs", [root, -1], cfg)
+        else:
+            Frontend(pg, cfg=cfg, width=2,
+                     policy="continuous").serve([root, root])
+    # the same drivers finish normally with room to spare
+    assert int(alg.bfs(pg, root, small_cfg()).stats.rounds) > 3

@@ -70,7 +70,7 @@ def test_fused_leg_call_single_launch_bit_identical(pad_lanes):
     want = jax.jit(_staged)(scalars, arrays)
     with tally() as t:
         got = jax.jit(lambda s, a: fused_leg_call(
-            _staged, s, a, interpret=True, pad_lanes=pad_lanes))(
+            _staged, s, a, pad_lanes=pad_lanes))(
                 scalars, arrays)
     assert t.n == 1, "a fused leg must be exactly ONE pallas_call"
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
@@ -85,7 +85,7 @@ def test_fused_leg_call_under_vmap_stays_one_launch():
     xs = jnp.asarray(rng.random((4, 6)), jnp.float32)
     fn = lambda x: (x * 2 + 1, x.sum())
     with tally() as t:
-        got = jax.vmap(lambda x: fused_leg_call(fn, x, interpret=True))(xs)
+        got = jax.vmap(lambda x: fused_leg_call(fn, x))(xs)
     assert t.n == 1
     want = jax.vmap(fn)(xs)
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):

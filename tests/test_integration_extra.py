@@ -57,8 +57,8 @@ def test_embedding_overflow_counter():
     class FakeAxis:
         pass
     # _routed_lookup_local needs an axis; run under a 1-device shard_map
-    from repro.launch.mesh import auto_mesh
-    mesh = auto_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import PartitionSpec as P
     table = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
     ids = jnp.zeros((6,), jnp.int32)  # all hit row 0 -> overflow beyond cap
@@ -66,10 +66,9 @@ def test_embedding_overflow_counter():
     def body(t, i):
         return _routed_lookup_local(t, i, capacity=2, axis="model", M=1)
 
-    from repro.core.comm import shard_map_compat
-    emb, ovf = jax.jit(shard_map_compat(
+    emb, ovf = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(None, None), P(None)),
-        out_specs=(P(None, None), P())))(table, ids)
+        out_specs=(P(None, None), P()), check_vma=False))(table, ids)
     assert int(ovf) == 4  # 6 lookups, capacity 2
     np.testing.assert_allclose(np.asarray(emb[:2]),
                                np.asarray(table[:1]).repeat(2, 0))
@@ -85,8 +84,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import store
 
 mode, d = sys.argv[1], sys.argv[2]
-from repro.launch.mesh import auto_mesh
-mesh = auto_mesh((len(jax.devices()),), ("data",))
+mesh = jax.make_mesh((len(jax.devices()),), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 tree = {"w": jnp.arange(32, dtype=jnp.float32).reshape(8, 4)}
 sh = {"w": NamedSharding(mesh, P("data", None))}
 if mode == "save":

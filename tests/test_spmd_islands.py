@@ -17,8 +17,8 @@ SCRIPT = textwrap.dedent("""
                                 to_dispatch_layout)
     from repro.parallel.sharding import (SINGLE_POD_RULES, mesh_context)
 
-    from repro.launch.mesh import auto_mesh
-    mesh = auto_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = SINGLE_POD_RULES
 
     # ---- routed embedding == plain gather ----
@@ -102,7 +102,8 @@ SCRIPT = textwrap.dedent("""
 
     # ---- pipeline over 8 stages == sequential ----
     from repro.parallel.pipeline import pipeline_apply
-    pmesh = auto_mesh((8,), ("stage",))
+    pmesh = jax.make_mesh((8,), ("stage",),
+                          axis_types=(jax.sharding.AxisType.Auto,))
     n_st, n_micro, mb, dd = 8, 16, 4, 8
     ks = jax.random.split(jax.random.PRNGKey(2), 2)
     w = jax.random.normal(ks[0], (n_st, dd, dd)) * 0.3
@@ -155,8 +156,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.layers import blockwise_attention
 from repro.parallel.ring import ring_attention
 
-from repro.launch.mesh import auto_mesh
-mesh = auto_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for (B, S, H, Hkv, hd, win) in [(2, 64, 4, 2, 16, 0), (2, 64, 4, 4, 16, 24),
                                 (4, 128, 2, 1, 32, 0)]:
     ks = jax.random.split(jax.random.PRNGKey(S + hd), 3)
