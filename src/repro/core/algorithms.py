@@ -177,14 +177,16 @@ class Result:
 
 def _call(pg, alg, cfg, value, frontier, mesh=None, axis="x", acc=None):
     """One engine run on either comm path: ``(value, acc, stats, trace)``.
-    A run that ``max_rounds`` cut with work still pending raises."""
+    A run that ``max_rounds`` cut with work still pending, or that
+    dropped messages, raises."""
     if mesh is None:
         out = local_engine_call(pg, alg, cfg, value, frontier, acc)
     else:
         out = spmd_engine_call(pg, alg, cfg, value, frontier, mesh, axis,
                                acc)
     *result, pending = out
-    check_finished(cfg, pending, f"program {as_program(alg).name!r}")
+    check_finished(cfg, pending, f"program {as_program(alg).name!r}",
+                   drops=result[2].drops)
     return result
 
 

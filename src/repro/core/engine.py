@@ -33,7 +33,9 @@ physical NoCs, per-link flits); overflow *spills* back into the channel's
 local queue — of whichever tile the message is stranded at, since routes
 are re-derived from the head flit — and is replayed next round, the
 software form of the paper's "CQ full -> early exit, resume next
-invocation".  Nothing is ever dropped; tests assert ``drops == 0``.
+invocation".  Nothing is ever dropped: the TSU's credit stop (below)
+keeps every queue from overflowing, and the host drivers raise on a run
+with ``Stats.drops > 0``.
 
 Scheduling: per-round budgets are chosen per device by a generic arbiter
 over the N channel queue occupancies plus the NoC's fed-back link occupancy
@@ -44,6 +46,9 @@ channel DAG: the deepest consumer always drains in full, and a channel's
 budget is quartered while any *downstream* queue (or the fabric) is
 congested; the frontier source stops entirely.  ``policy="static"``
 reproduces the paper's round-robin arbitration rung of the Fig. 5 ablation.
+Under both, a producer stops while any downstream queue on any tile lacks
+room for one round's worst-case inflow: end-to-end credit, since a tile's
+inflow comes from other tiles' pops.
 
 Synchronization: ``mode="async"`` is barrierless Dalorex — vertices
 re-armed by a fold re-enter the *live* frontier immediately.  ``mode="bsp"``
@@ -88,7 +93,7 @@ from repro.core.comm import AxisComm, LocalComm
 from repro.core.graph import PartitionedGraph
 from repro.core.program import (BFS, PAGERANK, SPMV, SSSP,  # noqa: F401
                                 WCC, AlgSpec, Ctx, INF, Program, TaskSpec,
-                                as_program, resolve_edge_space)
+                                as_program, edge_rows, resolve_edge_space)
 from repro.core.queues import (Queue, f2i, i2f, queue_make, queue_push,
                                queue_take_front)
 from repro.kernels.engine import (fifo_turn, fused_leg_call, queue_append,
@@ -333,11 +338,15 @@ def zero_stats(cfg: EngineConfig, T: int, alg=BFS) -> Stats:
 
 
 class GraphShard(NamedTuple):
-    """One device's chunk of the four dataset arrays (placed space)."""
+    """One device's chunk of the four dataset arrays (placed space), plus
+    the xla edge scan's row layout of the two edge arrays
+    (``program.edge_rows``), which :func:`make_round` builds once per run
+    for the legs that read it."""
     ptr_start: jax.Array  # (v_chunk,) i32 global placed edge index
     deg: jax.Array        # (v_chunk,) i32
     edge_dst: jax.Array   # (e_chunk,) i32 placed dst (-1 pad)
     edge_val: jax.Array   # (e_chunk,) f32
+    edge_rows: tuple = ()  # (dst rows, val rows), or () before make_round
 
 
 # --------------------------------------------------------------------------
@@ -345,7 +354,7 @@ class GraphShard(NamedTuple):
 # --------------------------------------------------------------------------
 
 def _budgets(cfg: EngineConfig, prog: Program, qcaps, pops, st: EngineState,
-             plimit: int):
+             plimit: int, full):
     """Per-round budgets from the channel queue occupancies AND the NoC's
     per-link occupancy fed back from last round (Section III-E).
 
@@ -355,13 +364,37 @@ def _budgets(cfg: EngineConfig, prog: Program, qcaps, pops, st: EngineState,
     queue is congested (> 3/4 full) or the fabric is hot; the frontier
     source stops entirely while channel 0 is half full or anything
     downstream is congested.  Returns (source_budget, (K,) channel pops).
+
+    Under either policy two credit rules keep ``drops`` at 0 (the local
+    throttle alone does not):
+
+    * ``full`` is the grid-wide check, ``(K,)`` i32: channel ``j``'s
+      queue on some tile has less room than one round's worst-case
+      inflow.  Every producer upstream of such a channel pops nothing,
+      since the messages it would deliver could land on that tile.
+    * A queued channel 0 re-pushes the split remainder of every task it
+      pops, and the spilled message too, so popping can grow it.  Its pop
+      is clamped to the room the source leaves, but never below its
+      per-destination capacity: that many messages always fit the ideal
+      fabric, so the queue cannot grow from them.
     """
     K = len(prog.channels)
     occ = [st.queues[i].count for i in range(K)]
     free0 = jnp.int32(qcaps[0]) - occ[0]
+    # stopped[i]: some channel downstream of i is out of credit
+    stopped = [full[i + 1:].any() for i in range(K - 1)] + [False]
+
+    def clamp_first(f_pop, chan_pops):
+        if prog.channels[0].queued:
+            room = jnp.maximum(free0 - f_pop,
+                               prog.channels[0].route_cap(cfg))
+            chan_pops[0] = jnp.minimum(chan_pops[0], room)
+        return f_pop, jnp.stack(chan_pops)
+
     if cfg.policy == "static":
         f_pop = jnp.minimum(jnp.int32(cfg.f_pop), jnp.maximum(free0, 0))
-        return f_pop, jnp.stack([jnp.int32(p) for p in pops])
+        return clamp_first(f_pop, [jnp.where(stopped[i], 0, jnp.int32(p))
+                                   for i, p in enumerate(pops)])
     net_hot = st.net_pressure > jnp.int32(max(plimit, 1))
     congested = [occ[i] > (3 * qcaps[i]) // 4 for i in range(K)]
     chan_pops = [None] * K
@@ -377,9 +410,10 @@ def _budgets(cfg: EngineConfig, prog: Program, qcaps, pops, st: EngineState,
             # clears; the last channel always drains, so this cannot
             # deadlock.
             throttled = pops[i] // 4 if K == 2 else 0
-            chan_pops[i] = jnp.where(down | net_hot,
-                                     jnp.int32(throttled),
-                                     jnp.int32(pops[i]))
+            chan_pops[i] = jnp.where(
+                stopped[i], jnp.int32(0),
+                jnp.where(down | net_hot, jnp.int32(throttled),
+                          jnp.int32(pops[i])))
         down = down | congested[i]
     down_of_source = net_hot
     for i in range(1, K):
@@ -389,7 +423,7 @@ def _budgets(cfg: EngineConfig, prog: Program, qcaps, pops, st: EngineState,
         half0 | down_of_source, jnp.int32(0),
         jnp.minimum(jnp.int32(cfg.f_pop),
                     jnp.maximum(free0 - 2 * cfg.f_pop, 0)))
-    return f_pop, jnp.stack(chan_pops)
+    return clamp_first(f_pop, chan_pops)
 
 
 def pending_work(me, st: EngineState):
@@ -495,6 +529,15 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
         return ctx._replace(backend=backends[chan_i],
                             fused=leg_fused[leg_i])
 
+    # The xla edge scan reads the shard in row layout; a fused leg reads
+    # the flat arrays inside its kernel, so only unfused legs carry it.
+    shard_rows = shard._replace(edge_rows=comm.run(
+        lambda me, sh: (edge_rows(sh.edge_dst, cfg.max_t2, -1),
+                        edge_rows(sh.edge_val, cfg.max_t2, 0.0)), shard))
+
+    def leg_shard(leg_i):
+        return shard if leg_fused[leg_i] else shard_rows
+
     def wrap_leg(stage, leg_i):
         """Fused legs: the whole per-tile stage becomes one pallas_call
         body (intermediates VMEM-resident), via fused_leg_call."""
@@ -509,6 +552,14 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
     caps = tuple(ch.route_cap(cfg) for ch in chans)
     pops = tuple(ch.pop_budget(cfg) for ch in chans)
     qcaps = tuple(ch.qcap(cfg) for ch in chans)
+    inflow = prog.round_inflow(cfg, comm.size)
+
+    def out_of_credit(me, st):
+        """(K,) i32: which of this tile's queues could overflow in one
+        round of worst-case inflow (the input of _budgets' ``full``)."""
+        return jnp.stack([(jnp.int32(qcaps[j]) - st.queues[j].count
+                           < inflow[j]).astype(jnp.int32)
+                          for j in range(K)])
     owners = tuple(ch.owner_fn(ctx) for ch in chans)
     plimit = net.pressure_limit(cfg, caps)
     pp = cfg.perf
@@ -593,8 +644,8 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
 
     cx_first = leg_ctx(0, 0)
 
-    def stage_first(me, sh, st):
-        f_pop, dyn_pops = _budgets(cfg, prog, qcaps, pops, st, plimit)
+    def stage_first(me, sh, st, full):
+        f_pop, dyn_pops = _budgets(cfg, prog, qcaps, pops, st, plimit, full)
         st, rows, valid = prog.source(cx_first, me, sh, st, f_pop)
         st, msgs, mvalid, drops, npop, npush = ingest(
             0, st, rows, valid, dyn_pops[0], cx_first)
@@ -639,9 +690,10 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
         # ARE this round's launch count (repro.kernels.engine.launches) —
         # a Python int folded into Stats.launches, identical under
         # LocalComm/vmap, shard_map and the serving-lane vmap.
+        full = comm.pmax(comm.run(out_of_credit, st))
         with tally() as launch_tally:
             st, msgs, mvalid, drops, dyn_pops, n_pop, n_push = comm.run(
-                stage_first, shard, st)
+                stage_first, leg_shard(0), st, full)
             routed = net.route(comm, msgs, mvalid, caps[0], owners[0])
             link_round = routed.link_flits
             hop_round = routed.hop_hist
@@ -666,7 +718,8 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
                 if edge_space == "hbm" and chans[i - 1].work == "edges":
                     hbm_win = count_windows(hbm_win, routed.recv_valid)
                 st, msgs, mvalid, d, work, npop, npush, nspill = comm.run(
-                    make_mid(i), shard, st, routed.recv, routed.recv_valid,
+                    make_mid(i), leg_shard(i), st, routed.recv,
+                    routed.recv_valid,
                     routed.spill, routed.spill_valid, dyn_pops)
                 drops = drops + d
                 n_pop = n_pop + npop
@@ -684,7 +737,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
                 spillv.append(routed.spill_valid)
             if edge_space == "hbm" and chans[K - 1].work == "edges":
                 hbm_win = count_windows(hbm_win, routed.recv_valid)
-            st, d, work, nspill = comm.run(stage_last, shard, st,
+            st, d, work, nspill = comm.run(stage_last, leg_shard(K), st,
                                            routed.recv, routed.recv_valid,
                                            routed.spill,
                                            routed.spill_valid)
@@ -781,8 +834,8 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
             # the TSU's source grant, recomputed from the same pre-round
             # state stage_first arbitrated on (same integer math)
             src_grant = comm.run(
-                lambda me, s: _budgets(cfg, prog, qcaps, pops, s,
-                                       plimit)[0], st0)
+                lambda me, s, f: _budgets(cfg, prog, qcaps, pops, s,
+                                          plimit, f)[0], st0, full)
             tbuf = record_round(tbuf, dict(
                 cyc=cyc_round,
                 cyc_total=cycles_acc,
@@ -880,13 +933,19 @@ def run_engine(comm, cfg: EngineConfig, alg, shard: GraphShard,
     return st, stats, (tbuf if cfg.trace else None), pending
 
 
-def check_finished(cfg: EngineConfig, left, what: str, unit: str = "tasks"):
-    """Raise when a run stopped at ``cfg.max_rounds`` with ``left`` work
-    (pending tasks, or unfinished query lanes) still to do: its values
-    are partial, and returning them as a result would hide the cut."""
-    left = int(left)
+def check_finished(cfg: EngineConfig, left, what: str, unit: str = "tasks",
+                   drops=0):
+    """Raise when a run's values are not its result: it stopped at
+    ``cfg.max_rounds`` with ``left`` work (pending tasks, or unfinished
+    query lanes) still to do, or a full queue dropped ``drops``
+    messages.  Returning either as a result would hide it."""
+    left, drops = int(left), int(drops)
     if left:
         raise RuntimeError(
             f"{what} stopped at max_rounds={cfg.max_rounds} with {left} "
             f"{unit} left: its values are partial (raise "
             f"EngineConfig.max_rounds)")
+    if drops:
+        raise RuntimeError(
+            f"{what} dropped {drops} messages at a full queue: its values "
+            f"are wrong (raise cap_rangeq/cap_updq)")

@@ -57,14 +57,20 @@ class CSRGraph:
     @staticmethod
     def from_edges(n: int, src: np.ndarray, dst: np.ndarray,
                    val: np.ndarray | None = None, dedup: bool = True) -> "CSRGraph":
-        if val is None:
-            val = np.ones(len(src), np.float32)
-        if dedup and len(src):
+        if val is None and dedup and len(src):
+            # unweighted: every copy of an edge is the same, so the sorted
+            # unique keys alone are the CSR (no stable argsort, no gathers)
+            key = np.unique(src.astype(np.int64) * n + dst.astype(np.int64))
+            src, dst = key // n, key % n
+            val = np.ones(len(key), np.float32)
+        elif dedup and len(src):
             # unique keys come back sorted by (src, dst): already CSR order
             key = src.astype(np.int64) * n + dst.astype(np.int64)
             _, idx = np.unique(key, return_index=True)
             src, dst, val = src[idx], dst[idx], val[idx]
         else:
+            if val is None:
+                val = np.ones(len(src), np.float32)
             order = np.lexsort((dst, src))
             src, dst, val = src[order], dst[order], val[order]
         counts = np.bincount(src, minlength=n)
@@ -239,17 +245,25 @@ def rmat_edges(scale: int, edge_factor: int = 10, a: float = 0.57, b: float = 0.
     rng = np.random.default_rng(seed)
     n = 1 << scale
     m = n * edge_factor
-    src = np.zeros(m, np.int64)
-    dst = np.zeros(m, np.int64)
+    word = np.uint32 if scale <= 32 else np.uint64
+    src = np.zeros(m, word)
+    dst = np.zeros(m, word)
+    # conditional probabilities of a dst bit per src-bit quadrant
+    p_dst0 = b / (a + b)
+    p_dst1 = (1 - (a + b + c)) / (1 - (a + b))
+    # one set of work buffers for all levels (the arrays are large)
+    r1, r2 = np.empty(m), np.empty(m)
+    src_bit, dst_bit, alt = (np.empty(m, bool) for _ in range(3))
     for bit in range(scale):
-        r1 = rng.random(m)
-        r2 = rng.random(m)
-        src_bit = (r1 > a + b).astype(np.int64)
-        # conditional probabilities per quadrant
-        p_dst = np.where(src_bit == 0, b / (a + b), (1 - (a + b + c)) / (1 - (a + b)))
-        dst_bit = (r2 < p_dst).astype(np.int64)
-        src |= src_bit << bit
-        dst |= dst_bit << bit
+        rng.random(out=r1)
+        rng.random(out=r2)
+        np.greater(r1, a + b, out=src_bit)
+        np.less(r2, p_dst0, out=dst_bit)
+        np.less(r2, p_dst1, out=alt)
+        np.copyto(dst_bit, alt, where=src_bit)
+        src += src_bit.view(np.uint8).astype(word) << word(bit)
+        dst += dst_bit.view(np.uint8).astype(word) << word(bit)
+    src, dst = src.astype(np.int64), dst.astype(np.int64)
     if weights == "uniform":
         val = rng.uniform(1.0, 10.0, m).astype(np.float32)
     else:

@@ -268,19 +268,18 @@ class Program:
     edge_space: Optional[str] = None
     state_space: str = "vmem"
 
-    def min_caps(self, cfg, T: int) -> tuple:
+    def round_inflow(self, cfg, T: int) -> tuple:
         """Per-channel worst-case one-round queue inflow.
 
         Queued channels absorb fresh tasks plus their own re-pushed split
         remainders; spill-only channels absorb the predecessor handler's
         full burst behind the replay budget.  Physical NoCs additionally
         spill mid-route messages into *waypoint* queues, so every inbound
-        CQ slot of the leg must fit too.  ``EngineConfig.validate`` keeps
-        the closed-form twin of this for the classic program shape.
-        """
+        CQ slot of the leg must fit too.  The TSU stops a channel's
+        producers while its queue has less room than this
+        (``engine._budgets``)."""
         physical = cfg.noc != "ideal"
-        deep = len(self.channels) > 2
-        needs = []
+        out = []
         for i, ch in enumerate(self.channels):
             cap_i = ch.route_cap(cfg)
             pop_i = ch.pop_budget(cfg)
@@ -292,10 +291,26 @@ class Program:
             inflow = feed + pop_i
             if physical:
                 inflow += pop_i + T * cap_i if ch.queued else T * cap_i
+            out.append(inflow)
+        return tuple(out)
+
+    def min_caps(self, cfg, T: int) -> tuple:
+        """Per-channel queue capacity needed so that no queue drops: one
+        round's worst-case inflow (:meth:`round_inflow`) fits.
+        ``EngineConfig.validate`` keeps the closed-form twin of this for
+        the classic program shape.
+        """
+        physical = cfg.noc != "ideal"
+        deep = len(self.channels) > 2
+        needs = []
+        for i, (ch, inflow) in enumerate(zip(self.channels,
+                                             self.round_inflow(cfg, T))):
+            cap_i = ch.route_cap(cfg)
+            pop_i = ch.pop_budget(cfg)
             if i == 0 and ch.queued:
                 # the frontier source clamps itself to the queue's free
                 # space, so the legacy 2x margin suffices.
-                need = 2 * feed
+                need = 2 * cfg.f_pop
                 if physical:
                     need += 2 * pop_i + T * cap_i
             elif deep:
@@ -306,8 +321,9 @@ class Program:
                 # cap >= 3/4*cap + inflow  <=>  cap >= 4*inflow.
                 need = 4 * inflow
             else:
-                # classic shape: the seed's empirically-validated burst
-                # bound (EngineConfig.min_caps keeps the closed form).
+                # classic shape: one round's inflow fits, and the TSU's
+                # credit stop holds the producers back while it would not
+                # (EngineConfig.min_caps keeps the closed form).
                 need = inflow
             needs.append(need)
         return tuple(needs)
@@ -421,21 +437,34 @@ def sized_cfg(cfg, program: Program, T: int):
 # --------------------------------------------------------------------------
 
 def take_first_k(mask: jax.Array, k: jax.Array, k_max: int):
-    """Indices of the first ``min(k, popcount)`` set bits, FIFO by position.
+    """Indices of the first ``min(k, popcount)`` set bits, FIFO by position
+    (``k <= k_max``).
 
-    The i-th taken bit is where the inclusive popcount prefix first
-    reaches i+1, so a cumsum and a ``k_max``-query binary search find
-    them without sorting the whole bitmap.  Invalid slots hold clamped
-    positions (don't-cares, masked by ``valid`` downstream).
+    The bitmap is read as rows of 128 bits: a prefix sum over the row
+    popcounts finds the row holding the i-th set bit, and a prefix sum
+    inside that row (one row gather per query) finds its column, so no
+    prefix sum or search runs over the whole bitmap.  The cleared mask
+    keeps the set bits after the last one taken.  Invalid slots hold
+    clamped positions (don't-cares, masked by ``valid`` downstream).
 
     Returns (idx (k_max,) i32, valid (k_max,) bool, cleared_mask)."""
     n = mask.shape[0]
-    csum = jnp.cumsum(mask.astype(jnp.int32))
+    width = 128
+    nrow = -(-n // width)
+    bits = jnp.pad(mask.astype(jnp.int32),
+                   (0, nrow * width - n)).reshape(nrow, width)
+    row_csum = jnp.cumsum(bits.sum(axis=1))
+    taken = jnp.minimum(k, row_csum[-1])
     want = jnp.arange(1, k_max + 1, dtype=jnp.int32)
-    idx = jnp.searchsorted(csum, want, side="left", method="scan_unrolled")
-    valid = want <= jnp.minimum(k, csum[-1])
-    return (jnp.minimum(idx, n - 1).astype(jnp.int32), valid,
-            mask & (csum > k))
+    row = jnp.minimum(jnp.searchsorted(row_csum, want, side="left",
+                                       method="compare_all"), nrow - 1)
+    rank = want - jnp.where(row > 0, row_csum[row - 1], 0)
+    col = jnp.argmax(jnp.cumsum(bits[row], axis=1) >= rank[:, None],
+                     axis=1)
+    idx = jnp.minimum(row * width + col, n - 1).astype(jnp.int32)
+    last = jnp.where(taken > 0, idx[jnp.maximum(taken - 1, 0)], -1)
+    return (idx, want <= taken,
+            mask & (jnp.arange(n, dtype=jnp.int32) > last))
 
 
 def frontier_source(payload: Callable) -> Callable:
@@ -485,36 +514,47 @@ def range_split(ctx: Ctx, taken: jax.Array, tvalid: jax.Array):
     return msgs, tvalid, rem, tvalid & (stop < t_end)
 
 
-def segment_windows(edge_dst: jax.Array, edge_val: jax.Array,
+def edge_rows(edge: jax.Array, max_t2: int, fill) -> jax.Array:
+    """A tile's edge array (leading axes kept) as ``(rows, width)`` with
+    ``width`` the larger of 128 and ``max_t2`` (a power of two), padded
+    with ``fill`` to one whole row past its end: the layout
+    :func:`segment_windows` reads.  Built once per engine run."""
+    width = max(128, 1 << (max(max_t2, 1) - 1).bit_length())
+    n = edge.shape[-1]
+    nrow = -(-n // width) + 1
+    pad = [(0, 0)] * (edge.ndim - 1) + [(0, nrow * width - n)]
+    return jnp.pad(edge, pad, constant_values=fill).reshape(
+        edge.shape[:-1] + (nrow, width))
+
+
+def segment_windows(dst_rows: jax.Array, val_rows: jax.Array,
                     local0: jax.Array, max_t2: int):
     """``(R, max_t2)`` windows ``edge_*[local0 + j]`` of the tile's shard,
     exact wherever ``local0 + j`` is inside it (every lane of a segment
     that ``range_split`` bounded at the chunk border).
 
-    Each message's segment is contiguous, so it is read as one slice
-    rather than ``max_t2`` single-word gathers, which a TPU serves one
-    random HBM access at a time.  A slice that would run past the shard
-    is moved back to end at its last word, and its rows are shifted
-    left again by a barrel shifter (log2(max_t2) selects)."""
-    e_chunk = edge_dst.shape[0]
-    m = min(max_t2, e_chunk)
-    first = jnp.minimum(local0, e_chunk - m)
-    shift = local0 - first                       # in [0, m)
+    The shard is read in the :func:`edge_rows` layout: the two whole rows
+    that cover each segment are gathered, and shifted left by the
+    segment's offset in its row with a barrel shifter.  A TPU gathers
+    whole rows quickly, but serves a slice at an unaligned offset, or a
+    single word, as one access at a time."""
+    width = dst_rows.shape[-1]
+    row, shift = local0 // width, local0 % width
 
-    def windows(arr):
-        win = jax.vmap(
-            lambda s: jax.lax.dynamic_slice_in_dim(arr, s, m))(first)
-        if m < max_t2:  # shard shorter than a segment bound
-            win = jnp.pad(win, ((0, 0), (0, max_t2 - m)), mode="edge")
-        b = 1
-        while b < m:
-            moved = jnp.concatenate(
-                [win[:, b:], jnp.repeat(win[:, -1:], b, axis=1)], axis=1)
-            win = jnp.where(((shift & b) != 0)[:, None], moved, win)
-            b *= 2
+    def windows(rows):
+        win = jnp.concatenate([rows[row], rows[row + 1]], axis=1)
+        # shift by the high bits first; after bit b only the first
+        # max_t2 + b - 1 columns can still reach the window
+        b = width // 2
+        win = win[:, :max_t2 + 2 * b - 1]
+        while b:
+            moved = jnp.concatenate([win[:, b:], win[:, :b]], axis=1)
+            win = jnp.where(((shift & b) != 0)[:, None], moved,
+                            win)[:, :max_t2 + b - 1]
+            b //= 2
         return win
 
-    return windows(edge_dst), windows(edge_val)
+    return windows(dst_rows), windows(val_rows)
 
 
 def edge_scan(emit_rows: Callable) -> Callable:
@@ -557,8 +597,7 @@ def edge_scan(emit_rows: Callable) -> Callable:
             local0 = jnp.where(rv, r_start % ctx.e_chunk, 0)
             j = jnp.arange(ctx.cfg.max_t2, dtype=jnp.int32)[None, :]
             jvalid = rv[:, None] & (j < length[:, None])
-            nb, w = segment_windows(sh.edge_dst, sh.edge_val, local0,
-                                    ctx.cfg.max_t2)
+            nb, w = segment_windows(*sh.edge_rows, local0, ctx.cfg.max_t2)
             jvalid = jvalid & (nb >= 0)
         rows, ov = emit_rows(ctx, recv, nb, w, jvalid)
         edges = jvalid.sum(dtype=jnp.int32)

@@ -390,13 +390,13 @@ class Frontend:
             pending = np.asarray(carry.pending)
             # a segment ends when a lane finishes (halt), when every lane
             # is idle, or when max_rounds cuts it
-            if not bool(carry.halt):
-                check_finished(cfg, (pending > 0).sum(),
-                               f"continuous {self.app} serving",
-                               "query lanes")
+            lane_drops = np.asarray(carry.stats.drops)
+            check_finished(cfg, 0 if bool(carry.halt) else
+                           (pending > 0).sum(),
+                           f"continuous {self.app} serving", "query lanes",
+                           drops=lane_drops.sum())
             lane_rounds = np.asarray(carry.stats.rounds)
             lane_edges = np.asarray(carry.stats.edges_scanned)
-            lane_drops = np.asarray(carry.stats.drops)
             for lane in range(W):
                 if lane_qid[lane] >= 0 and pending[lane] == 0:
                     qid, s, t_enq, t_admit = lane_meta[lane]

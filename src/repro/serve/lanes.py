@@ -336,7 +336,8 @@ def multi_source(pg: PartitionedGraph, app: str, sources,
     ``mesh=None`` runs the LocalComm emulation; a mesh runs shard_map SPMD.
     Per-query results are bit-identical to solo :func:`repro.core.
     algorithms.bfs` / ``sssp`` runs at the same ``cfg``.  A batch that
-    ``cfg.max_rounds`` cut before every lane finished raises.
+    ``cfg.max_rounds`` cut before every lane finished, or that dropped
+    messages, raises.
     """
     if app not in ("bfs", "sssp"):
         raise ValueError(f"multi_source serves point queries (bfs/sssp), "
@@ -361,7 +362,8 @@ def multi_source(pg: PartitionedGraph, app: str, sources,
         if not cfg.trace:
             trace = None
     check_finished(cfg, (np.asarray(done_round) < 0).sum(),
-                   f"{app} batch of {len(sources)}", "query lanes")
+                   f"{app} batch of {len(sources)}", "query lanes",
+                   drops=np.asarray(stats.drops).sum())
     B = len(sources)
     flat = np.asarray(vals).reshape(B, -1)
     values = flat[:, np.asarray(pg.place)].astype(np.float64)

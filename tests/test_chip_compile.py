@@ -4,8 +4,10 @@ Nothing here runs on a chip.  The TPU compiler, which is installed with
 ``libtpu``, compiles for a ``v5e:2x2`` topology that is described and not
 attached, and refuses what the chip's compiler would refuse: an op it
 cannot lower, a program that does not fit the device's memory.  Both
-programs are compiled at the per-tile layout ``chip_smoke.py`` runs:
-Graph500 scale 22, edgefactor 16, T=16 tiles, edge shards declared in HBM.
+programs are compiled with the smoke's engine configuration at the
+per-tile layout of ``chip_smoke.py --scale 22`` (its default scale is
+smaller, to fit its time limit): Graph500 scale 22, edgefactor 16, T=16
+tiles, edge shards declared in HBM.
 
 The topology is described inside a module fixture (never at import, in a
 ``skipif`` or in a ``parametrize``): only one process at a time may load
@@ -81,7 +83,7 @@ def _device_bytes(compiled) -> int:
 
 
 def test_bfs_local_call_compiles_for_v5e(one_chip):
-    cfg = chip_smoke.smoke_cfg(T)
+    cfg = chip_smoke.engine_config(T)
     shard, (value, frontier, acc) = _shapes(one_chip)
     compiled = _local_call.lower(as_program(BFS), cfg, T, E_CHUNK, V_CHUNK,
                                  shard, value, frontier, acc).compile()
@@ -92,7 +94,7 @@ def test_bfs_local_call_compiles_for_v5e(one_chip):
 
 
 def test_serving_lanes_compile_for_v5e(one_chip):
-    cfg = chip_smoke.smoke_cfg(T)
+    cfg = chip_smoke.engine_config(T)
     shard, (value, frontier, acc) = _shapes(one_chip,
                                             lead=(chip_smoke.SERVE_WIDTH,))
     compiled = local_lanes_call.lower(as_program(BFS), cfg, T, E_CHUNK,

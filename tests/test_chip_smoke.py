@@ -50,5 +50,5 @@ def test_smoke_spmd_phase_on_one_device():
     root = chip_smoke.pick_vertices(g, 1, np.random.default_rng(1))[0]
     mesh = jax.make_mesh((1,), ("x",),
                          axis_types=(jax.sharding.AxisType.Auto,))
-    out = chip_smoke.phase_spmd(g, pg, chip_smoke.smoke_cfg(1), root, mesh)
+    out = chip_smoke.phase_spmd(g, pg, chip_smoke.engine_config(1), root, mesh)
     assert out["rounds"] > 0 and out["edges_scanned"] > 0

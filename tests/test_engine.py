@@ -161,3 +161,68 @@ def test_cut_run_raises(g, pg, driver):
                      policy="continuous").serve([root, root])
     # the same drivers finish normally with room to spare
     assert int(alg.bfs(pg, root, small_cfg()).stats.rounds) > 3
+
+
+def tight_cfg(**kw):
+    """An update queue of exactly one round's worst-case inflow, and an
+    update fabric far narrower than the edge scans' output, so the update
+    spill queue fills within a few rounds."""
+    base = dict(f_pop=32, r_pop=32, u_pop=8, max_t2=16, cap_route_range=16,
+                cap_route_update=4, cap_rangeq=128, **kw)
+    return EngineConfig(**base, cap_updq=EngineConfig(**base).min_caps(4)[1])
+
+
+@pytest.fixture(scope="module")
+def sym_g():
+    """Symmetrized and unweighted, as Graph500 BFS reads it."""
+    n, src, dst, _ = rmat_edges(10, edge_factor=8, seed=0)
+    return CSRGraph.from_edges(n, np.concatenate([src, dst]),
+                               np.concatenate([dst, src]))
+
+
+@pytest.fixture(scope="module")
+def sym_pg(sym_g):
+    return alg.prepare(sym_g, T=4)
+
+
+def without_credit_stop(monkeypatch):
+    """The TSU without its credit stop: no queue ever counts as short of
+    room for a round's inflow (only the local throttle is left)."""
+    from repro.core.program import Program
+    monkeypatch.setattr(Program, "round_inflow",
+                        lambda self, cfg, T: (0,) * len(self.channels))
+
+
+def test_credit_stop_keeps_tight_queues_from_dropping(sym_g, sym_pg,
+                                                      monkeypatch):
+    from repro.core.engine import BFS
+    root = pick_root(sym_g)
+    res = alg.bfs(sym_pg, root, tight_cfg(max_rounds=5001))
+    np.testing.assert_array_equal(res.values, ref.bfs_ref(sym_g, root))
+    assert int(res.stats.drops) == 0
+    # the same budgets overflow the update queue without the stop
+    without_credit_stop(monkeypatch)
+    value, frontier = alg.init_min_state(sym_pg, [root])
+    out = alg.local_engine_call(sym_pg, BFS, tight_cfg(max_rounds=5002),
+                                value, frontier)
+    assert int(out[2].drops) > 0
+
+
+@pytest.mark.parametrize("driver,max_rounds", [
+    ("algorithms", 5003), ("multi_source", 5004),
+    ("frontend_continuous", 5005)])
+def test_dropped_run_raises(sym_g, sym_pg, monkeypatch, driver, max_rounds):
+    """A run that dropped messages is an error in every host driver,
+    never a wrong result."""
+    from repro.serve import Frontend, multi_source
+    without_credit_stop(monkeypatch)
+    root = pick_root(sym_g)
+    cfg = tight_cfg(max_rounds=max_rounds)  # a fresh trace per driver
+    with pytest.raises(RuntimeError, match="dropped"):
+        if driver == "algorithms":
+            alg.bfs(sym_pg, root, cfg)
+        elif driver == "multi_source":
+            multi_source(sym_pg, "bfs", [root, -1], cfg)
+        else:
+            Frontend(sym_pg, cfg=cfg, width=2,
+                     policy="continuous").serve([root, root])
