@@ -1,0 +1,10 @@
+"""idle_share.bfs: the device's idle share of the traced window (one whole
+engine call and the host work before it, bench/run.py Profiler): one less
+the union of its operations' intervals over the window, averaged over the
+chips used (bfs cells)."""
+
+
+def read(run):
+    if run.trace is None or run.kind != "bfs":
+        return None
+    return run.trace["idle_share"]
