@@ -15,25 +15,48 @@ Two execution paths share all engine code:
 * ``comm=LocalComm(T)`` — T emulated tiles on one device (tests/benchmarks).
 * ``comm=AxisComm(axis, T)`` via :func:`spmd_engine_call` — real shard_map
   SPMD over a device mesh (the production / dry-run path).
+
+The drivers emit the host spans of :data:`HOST_SPANS` with
+``jax.profiler.TraceAnnotation``, on the profiler's clock beside the
+device operations; :func:`engine_leg_map` names the round leg
+(``engine.ROUND_LEGS``) of each device operation of the engine program.
+A span costs well under a microsecond when no profile is being taken
+(DESIGN.md "Device legs and host spans").
 """
 from __future__ import annotations
 
+import collections
+import contextvars
 import dataclasses
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.comm import AxisComm, LocalComm
-from repro.core.engine import (BFS, PAGERANK, SPMV, SSSP, WCC, AlgSpec,
-                               EngineConfig, EngineState, GraphShard, INF,
-                               Stats, check_finished, init_state,
-                               run_engine, zero_stats)
+from repro.core.engine import (BFS, PAGERANK, ROUND_LEGS, SPMV, SSSP, WCC,
+                               AlgSpec, EngineConfig, EngineState,
+                               GraphShard, INF, Stats, check_finished,
+                               init_state, run_engine, zero_stats)
 from repro.core.graph import CSRGraph, PartitionedGraph, partition_graph
 from repro.core.program import (TRIANGLES, as_program, kcore_program,
                                 sized_cfg)
 from repro.trace.buffer import zero_trace
+
+# The host spans of the drivers (jax.profiler.TraceAnnotation names):
+#   engine_call      _call as a whole; its arguments name the program and,
+#                    for PageRank, the epoch
+#   engine_dispatch  the jitted engine call returning (inside engine_call)
+#   engine_wait      the blocking read of pending work and drops
+#                    (check_finished, inside engine_call)
+#   init_state       building a run's placed state (init_*_state)
+#   epoch_update     PageRank's rank update between epochs
+#   to_original      mapping a result back to original vertex ids
+HOST_SPANS = ("engine_call", "engine_dispatch", "engine_wait", "init_state",
+              "epoch_update", "to_original")
 
 
 # --------------------------------------------------------------------------
@@ -45,6 +68,7 @@ def real_mask(pg: PartitionedGraph) -> np.ndarray:
     return (pg.inv >= 0).reshape(pg.T, pg.v_chunk)
 
 
+@partial(annotate_function, name="init_state")
 def init_min_state(pg: PartitionedGraph, roots: list[int]):
     """value=+inf except roots (=0); frontier = roots."""
     value = np.full((pg.T, pg.v_chunk), np.float32(np.finfo(np.float32).max))
@@ -57,6 +81,7 @@ def init_min_state(pg: PartitionedGraph, roots: list[int]):
     return jnp.asarray(value), jnp.asarray(frontier)
 
 
+@partial(annotate_function, name="init_state")
 def init_wcc_state(pg: PartitionedGraph):
     """Label = original vertex id; every real vertex starts in the frontier."""
     inv = pg.inv.reshape(pg.T, pg.v_chunk)
@@ -65,6 +90,7 @@ def init_wcc_state(pg: PartitionedGraph):
     return jnp.asarray(value, jnp.float32), jnp.asarray(frontier)
 
 
+@partial(annotate_function, name="init_state")
 def init_add_state(pg: PartitionedGraph, x: np.ndarray):
     """value = x scattered to placed slots; frontier = real vertices with
     out-edges (vertices with deg 0 emit nothing)."""
@@ -76,6 +102,7 @@ def init_add_state(pg: PartitionedGraph, x: np.ndarray):
     return jnp.asarray(value), jnp.asarray(frontier)
 
 
+@partial(annotate_function, name="init_state")
 def init_kcore_state(pg: PartitionedGraph, k: int):
     """value = remaining degree; acc = removed flag (1 = out of the core);
     the initially-dead vertices (deg < k, and padding) seed the frontier so
@@ -88,6 +115,7 @@ def init_kcore_state(pg: PartitionedGraph, k: int):
     return jnp.asarray(value), jnp.asarray(dead0), jnp.asarray(acc)
 
 
+@partial(annotate_function, name="to_original")
 def to_original(pg: PartitionedGraph, arr) -> np.ndarray:
     """(T, v_chunk) placed-space array -> (V,) original order."""
     flat = np.asarray(arr).reshape(-1)
@@ -102,7 +130,8 @@ def to_original(pg: PartitionedGraph, arr) -> np.ndarray:
 def _local_call(prog, cfg: EngineConfig, T: int, e_chunk: int,
                 v_chunk: int, shard: GraphShard, value, frontier, acc):
     comm = LocalComm(T)
-    st = init_state(comm, cfg, v_chunk, value, frontier, prog, acc)
+    with jax.named_scope("init"):
+        st = init_state(comm, cfg, v_chunk, value, frontier, prog, acc)
     st, stats, trace, pending = run_engine(comm, cfg, prog, shard, st,
                                            e_chunk, v_chunk)
     return st.value, st.acc, stats, trace, pending
@@ -130,19 +159,31 @@ def spmd_engine_call(pg: PartitionedGraph, alg, cfg: EngineConfig,
     the same ``(value, acc, stats, trace, pending)`` as
     :func:`local_engine_call`.
     """
+    if acc is None:
+        acc = jnp.zeros_like(value)
+    fn, sharding = _spmd_program(pg, as_program(alg), cfg, mesh, axis)
+    args = [jax.device_put(a, sharding) for a in
+            (pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val, value,
+             frontier, acc)]
+    return fn(*args)
+
+
+def _spmd_program(pg: PartitionedGraph, prog, cfg: EngineConfig, mesh,
+                  axis: str):
+    """The jitted shard_map engine of :func:`spmd_engine_call`, and the
+    sharding of its seven (T, chunk) operands."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     T = pg.T
-    prog = as_program(alg)
     comm = AxisComm(axis, T)
     spec2 = P(axis, None)
-    if acc is None:
-        acc = jnp.zeros_like(value)
 
     def body(ptr_start, deg, edge_dst, edge_val, value, frontier, acc):
-        shard = GraphShard(ptr_start[0], deg[0], edge_dst[0], edge_val[0])
-        st = init_state(comm, cfg, pg.v_chunk, value[0], frontier[0],
-                        prog, acc[0])
+        with jax.named_scope("init"):
+            shard = GraphShard(ptr_start[0], deg[0], edge_dst[0],
+                               edge_val[0])
+            st = init_state(comm, cfg, pg.v_chunk, value[0], frontier[0],
+                            prog, acc[0])
         st, stats, trace, pending = run_engine(comm, cfg, prog, shard, st,
                                                pg.e_chunk, pg.v_chunk)
         return st.value[None], st.acc[None], stats, trace, pending
@@ -157,10 +198,140 @@ def spmd_engine_call(pg: PartitionedGraph, alg, cfg: EngineConfig,
         out_specs=(spec2, spec2, jax.tree.map(lambda _: P(), Stats.zero()),
                    trace_spec, P()),
         check_vma=False)
-    args = [jax.device_put(a, NamedSharding(mesh, spec2)) for a in
-            (pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val, value,
-             frontier, acc)]
-    return jax.jit(fn)(*args)
+    return jax.jit(fn), NamedSharding(mesh, spec2)
+
+
+# The engine's named scopes: the round legs, the work before the round
+# loop, and the flight recorder's block.
+ENGINE_SCOPES = ROUND_LEGS + ("init", "recorder")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([^ ]+) .*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([^ ]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%([^ ,}]+)")
+_REF = re.compile(r"%([^\s,(){}]+)")
+_TRANSFORM = re.compile(r"^(?:\w+\()+|\)+$")
+
+
+def op_leg(op_name: str) -> str:
+    """The innermost engine scope in an HLO ``op_name`` (a name stack such
+    as ``jit(_local_call)/while/body/vmap(route)/link_count/scatter-add``,
+    where a transformation wraps a scope as ``vmap(route)``), or
+    "unscoped"."""
+    for part in reversed(op_name.split("/")):
+        part = _TRANSFORM.sub("", part)
+        if part in ENGINE_SCOPES:
+            return part
+    return "unscoped"
+
+
+def hlo_legs(hlo_text: str) -> dict[str, str]:
+    """{instruction name: leg} of every instruction of an HLO module's
+    text.  An instruction's leg is :func:`op_leg` of its
+    ``metadata={op_name=...}``; where that is "unscoped":
+
+    * a fusion takes the leg of its fused computation (that of the
+      instruction nearest the root that has one): the compiler drops a
+      fusion's metadata where it rewrites the root, as for a scatter;
+    * any other instruction the compiler added or lowered without the
+      name stack (a copy, a prefetch, a reduce-window of ``cumsum`` on
+      the CPU) takes the leg of the nearest instruction it feeds that has
+      one, else of the nearest that feeds it: the leg it serves;
+    * failing both, an instruction of a called computation (the body of
+      a loop the compiler made) takes the scope of the innermost
+      instruction calling it that has one.
+    """
+    scoped, calls, comps, refs = {}, {}, {}, {}
+    body = None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            body = comps.setdefault(m.group(1), [])
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m and body is not None:
+            name = m.group(1)
+            op, called = _OP_NAME.search(line), _CALLS.search(line)
+            scoped[name] = op_leg(op.group(1)) if op else "unscoped"
+            calls[name] = called and called.group(1)
+            refs[name] = _REF.findall(line[m.end():])
+            body.append(name)
+    caller = {c: n for n, r in refs.items() for c in r if c in comps}
+
+    def fused(name):
+        called = calls.pop(name, None)      # each fusion is resolved once
+        if scoped[name] == "unscoped" and called in comps:
+            scoped[name] = next((lg for lg in map(fused,
+                                                  reversed(comps[called]))
+                                 if lg != "unscoped"), "unscoped")
+        return scoped[name]
+
+    for name in scoped:
+        fused(name)
+    operands, users = {}, {}
+    for names in comps.values():
+        inside = set(names)
+        for n in names:
+            operands[n] = [r for r in refs[n] if r in inside and r != n]
+            for r in operands[n]:
+                users.setdefault(r, []).append(n)
+
+    def nearest(name, edges):
+        seen, todo = {name}, collections.deque(edges.get(name, ()))
+        while todo:
+            n = todo.popleft()
+            if n not in seen:
+                seen.add(n)
+                if scoped[n] != "unscoped":
+                    return scoped[n]
+                todo.extend(edges.get(n, ()))
+        return "unscoped"
+
+    comp_of = {n: c for c, names in comps.items() for n in names}
+
+    def called_from(name):
+        while scoped[name] == "unscoped" and comp_of[name] in caller:
+            name = caller[comp_of[name]]
+        return scoped[name]
+
+    legs = {}
+    for name, leg in scoped.items():
+        if leg == "unscoped":
+            leg = nearest(name, users)
+        if leg == "unscoped":
+            leg = nearest(name, operands)
+        if leg == "unscoped":
+            leg = called_from(name)
+        legs[name] = leg
+    return legs
+
+
+def engine_leg_map(pg: PartitionedGraph, alg, cfg: EngineConfig,
+                   mesh=None, axis: str = "x") -> dict[str, str]:
+    """{HLO instruction name: leg} of the optimized engine program that
+    :func:`_call` runs for ``pg``'s shapes: the names the profiler gives
+    the device operations (``fusion.442``, ``sort.475``; on the CPU also
+    ``wrapped_scatter``), each with the innermost of
+    :data:`ENGINE_SCOPES` it was traced in (:func:`hlo_legs`), else
+    "unscoped".  The program is lowered from shapes and compiled again:
+    after a call, the executable that call ran.  A persistent
+    compilation cache keyed without metadata (JAX's default) can hand a
+    program the executable of one that differs only in its scopes, whose
+    metadata then names no leg."""
+    prog = as_program(alg)
+    state = [jax.ShapeDtypeStruct((pg.T, pg.v_chunk), dt)
+             for dt in (jnp.float32, jnp.bool_, jnp.float32)]
+    arrays = (pg.ptr_start, pg.deg, pg.edge_dst, pg.edge_val)
+    if mesh is None:
+        shard = GraphShard(*(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                             for a in arrays))
+        lowered = _local_call.lower(prog, cfg, pg.T, pg.e_chunk,
+                                    pg.v_chunk, shard, *state)
+    else:
+        fn, sharding = _spmd_program(pg, prog, cfg, mesh, axis)
+        lowered = fn.lower(*(jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=sharding)
+                             for a in list(arrays) + state))
+    return hlo_legs(lowered.compile().as_text())
 
 
 # --------------------------------------------------------------------------
@@ -175,18 +346,30 @@ class Result:
     trace: object = None  # TraceBuf when cfg.trace, else None
 
 
+# PageRank's epoch, while it runs one: an argument of _call's span
+_EPOCH = contextvars.ContextVar("epoch", default=None)
+
+
 def _call(pg, alg, cfg, value, frontier, mesh=None, axis="x", acc=None):
     """One engine run on either comm path: ``(value, acc, stats, trace)``.
     A run that ``max_rounds`` cut with work still pending, or that
-    dropped messages, raises."""
-    if mesh is None:
-        out = local_engine_call(pg, alg, cfg, value, frontier, acc)
-    else:
-        out = spmd_engine_call(pg, alg, cfg, value, frontier, mesh, axis,
-                               acc)
-    *result, pending = out
-    check_finished(cfg, pending, f"program {as_program(alg).name!r}",
-                   drops=result[2].drops)
+    dropped messages, raises.  Its ``engine_call`` span names the program
+    and PageRank's epoch, the identifier its device work shares."""
+    name = as_program(alg).name
+    span = {"program": name}
+    if _EPOCH.get() is not None:
+        span["epoch"] = _EPOCH.get()
+    with TraceAnnotation("engine_call", **span):
+        with TraceAnnotation("engine_dispatch"):
+            if mesh is None:
+                out = local_engine_call(pg, alg, cfg, value, frontier, acc)
+            else:
+                out = spmd_engine_call(pg, alg, cfg, value, frontier, mesh,
+                                       axis, acc)
+        *result, pending = out
+        with TraceAnnotation("engine_wait"):
+            check_finished(cfg, pending, f"program {name!r}",
+                           drops=result[2].drops)
     return result
 
 
@@ -243,18 +426,25 @@ def pagerank(pg: PartitionedGraph, damping: float = 0.85, iters: int = 20,
     total = zero_stats(cfg, pg.T, PAGERANK)
     epochs = 0
     trace = None  # the LAST epoch's ring (each epoch restarts the engine)
-    for _ in range(iters):
-        frontier = jnp.asarray(real & (deg > 0))
-        _, acc, stats, trace = _call(pg, PAGERANK, cfg, jnp.asarray(rank),
-                                     frontier, mesh)
-        acc = np.asarray(acc)
-        dangling = rank[real & (deg == 0)].sum()
-        new_rank = np.where(
-            real, (1 - damping) / V + damping * (acc + dangling / V),
-            0.0).astype(np.float32)
-        diff = np.abs(new_rank - rank).sum()
-        rank = new_rank
-        total = _acc_stats(total, stats)
+    for epoch in range(iters):
+        with TraceAnnotation("init_state"):
+            frontier = jnp.asarray(real & (deg > 0))
+            value = jnp.asarray(rank)
+        token = _EPOCH.set(epoch)
+        try:
+            _, acc, stats, trace = _call(pg, PAGERANK, cfg, value, frontier,
+                                         mesh)
+        finally:
+            _EPOCH.reset(token)
+        with TraceAnnotation("epoch_update"):
+            acc = np.asarray(acc)
+            dangling = rank[real & (deg == 0)].sum()
+            new_rank = np.where(
+                real, (1 - damping) / V + damping * (acc + dangling / V),
+                0.0).astype(np.float32)
+            diff = np.abs(new_rank - rank).sum()
+            rank = new_rank
+            total = _acc_stats(total, stats)
         epochs += 1
         if tol and diff < tol:
             break

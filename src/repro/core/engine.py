@@ -73,6 +73,13 @@ traces them inline, "pallas" dispatches to the tile-grid kernels of
 in VMEM) — bit-identical by contract, per-channel overridable via
 ``TaskSpec.backend`` (DESIGN.md "Pallas backend").
 
+Each leg of a round runs inside a ``jax.named_scope`` named in
+:data:`ROUND_LEGS`, so a profile of the compiled engine charges every
+device operation to the innermost leg it came from
+(:func:`repro.core.algorithms.engine_leg_map`; DESIGN.md "Device legs and
+host spans").  Scopes are compile-time metadata: they change no value,
+and the optimized program only in its instructions' names.
+
 Everything here is single-query; the serving subsystem
 (:mod:`repro.serve`) vmaps the round built by :func:`make_round` over a
 leading *query-lane* axis so a batch of B traversals shares the resident
@@ -83,6 +90,7 @@ graph, the rounds and the fabric, freezing each lane with
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -104,6 +112,40 @@ from repro.noc.topology import N_LINK_CLASSES
 from repro.perf import (PerfParams, link_cost_vectors, round_energy_pj,
                         tile_compute_cycles)
 from repro.trace.buffer import record_round, zero_trace
+
+
+# The legs of one engine round, as the jax.named_scope names they run in:
+#   control     credit check, TSU budgets, idle-wire reductions, BSP swap,
+#               the round loop's condition
+#   source      the frontier pop (Program.source)
+#   queue       channel queue turns and spill re-queues (ingest, requeue)
+#   route       binning by owner and the exchange (Network.route)
+#   link_count  per-link / per-hop / per-die telemetry histograms inside
+#               Network.route (the TSU's net_pressure input)
+#   scan        handlers of "edges" channels (the edge scan)
+#   fold        every other handler (the vertex-owner folds)
+#   telemetry   the round's counters, the cycle/energy model, Stats
+# Work before the loop runs in "init"; the flight recorder's block in
+# "recorder" (only with cfg.trace).
+ROUND_LEGS = ("control", "source", "queue", "route", "link_count", "scan",
+              "fold", "telemetry")
+
+
+def _leg(name: str):
+    """Decorator: trace the function inside the named scope ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args):
+            with jax.named_scope(name):
+                return fn(*args)
+        return scoped
+    return wrap
+
+
+def handler_leg(ch: TaskSpec) -> str:
+    """The leg of a channel's handler: "scan" for edge scans, "fold" for
+    the rest (vertex-owner handlers, triangles' wedge among them)."""
+    return "scan" if ch.work == "edges" else "fold"
 
 
 # --------------------------------------------------------------------------
@@ -531,9 +573,9 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
 
     # The xla edge scan reads the shard in row layout; a fused leg reads
     # the flat arrays inside its kernel, so only unfused legs carry it.
-    shard_rows = shard._replace(edge_rows=comm.run(
+    shard_rows = shard._replace(edge_rows=comm.run(_leg("init")(
         lambda me, sh: (edge_rows(sh.edge_dst, cfg.max_t2, -1),
-                        edge_rows(sh.edge_val, cfg.max_t2, 0.0)), shard))
+                        edge_rows(sh.edge_val, cfg.max_t2, 0.0))), shard))
 
     def leg_shard(leg_i):
         return shard if leg_fused[leg_i] else shard_rows
@@ -554,6 +596,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
     qcaps = tuple(ch.qcap(cfg) for ch in chans)
     inflow = prog.round_inflow(cfg, comm.size)
 
+    @_leg("control")
     def out_of_credit(me, st):
         """(K,) i32: which of this tile's queues could overflow in one
         round of worst-case inflow (the input of _budgets' ``full``)."""
@@ -573,6 +616,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
             (_cls[None, :] == np.arange(N_LINK_CLASSES)[:, None])
             .astype(np.int32))
 
+    @_leg("queue")
     def requeue(st, i, sp, spv, cx):
         """Spill re-queue into channel i's local queue.  Inside a fused leg
         this is the in-kernel :func:`queue_append` body (bit-identical to
@@ -585,6 +629,7 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
             q, d = queue_push(q, sp, spv)
         return _set_queue(st, i, q), d
 
+    @_leg("queue")
     def ingest(i, st, rows, valid, pop_i, cx):
         """Feed fresh rows into channel i and produce its network messages.
 
@@ -645,8 +690,11 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
     cx_first = leg_ctx(0, 0)
 
     def stage_first(me, sh, st, full):
-        f_pop, dyn_pops = _budgets(cfg, prog, qcaps, pops, st, plimit, full)
-        st, rows, valid = prog.source(cx_first, me, sh, st, f_pop)
+        with jax.named_scope("control"):
+            f_pop, dyn_pops = _budgets(cfg, prog, qcaps, pops, st, plimit,
+                                       full)
+        with jax.named_scope("source"):
+            st, rows, valid = prog.source(cx_first, me, sh, st, f_pop)
         st, msgs, mvalid, drops, npop, npush = ingest(
             0, st, rows, valid, dyn_pops[0], cx_first)
         return st, msgs, mvalid, drops, dyn_pops, npop, npush
@@ -659,21 +707,25 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
 
         def stage(me, sh, st, recv, rv, sp, spv, dyn_pops):
             st, d0 = requeue(st, i - 1, sp, spv, cx_h)
-            st, rows, valid, work = chans[i - 1].handler(
-                cx_h, me, sh, st, recv, rv)
+            with jax.named_scope(handler_leg(chans[i - 1])):
+                st, rows, valid, work = chans[i - 1].handler(
+                    cx_h, me, sh, st, recv, rv)
             st, msgs, mvalid, d1, npop, npush = ingest(
                 i, st, rows, valid, dyn_pops[i], cx_q)
-            nspill = spv.sum(dtype=jnp.int32)
-            return st, msgs, mvalid, d0 + d1, work, npop, npush, nspill
+            with jax.named_scope("telemetry"):
+                nspill = spv.sum(dtype=jnp.int32)
+                return st, msgs, mvalid, d0 + d1, work, npop, npush, nspill
         return wrap_leg(stage, i)
 
     cx_last = leg_ctx(K - 1, K)
 
     def stage_last(me, sh, st, recv, rv, sp, spv):
         st, d0 = requeue(st, K - 1, sp, spv, cx_last)
-        st, _, _, work = chans[K - 1].handler(cx_last, me, sh, st, recv,
-                                              rv)
-        return st, d0, work, spv.sum(dtype=jnp.int32)
+        with jax.named_scope(handler_leg(chans[K - 1])):
+            st, _, _, work = chans[K - 1].handler(cx_last, me, sh, st, recv,
+                                                  rv)
+        with jax.named_scope("telemetry"):
+            return st, d0, work, spv.sum(dtype=jnp.int32)
 
     stage_last = wrap_leg(stage_last, K)
 
@@ -690,20 +742,27 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
         # ARE this round's launch count (repro.kernels.engine.launches) —
         # a Python int folded into Stats.launches, identical under
         # LocalComm/vmap, shard_map and the serving-lane vmap.
-        full = comm.pmax(comm.run(out_of_credit, st))
+        with jax.named_scope("control"):
+            full = comm.pmax(comm.run(out_of_credit, st))
+
+        def route(i, msgs, mvalid):
+            with jax.named_scope("route"):
+                return net.route(comm, msgs, mvalid, caps[i], owners[i])
+
         with tally() as launch_tally:
             st, msgs, mvalid, drops, dyn_pops, n_pop, n_push = comm.run(
                 stage_first, leg_shard(0), st, full)
-            routed = net.route(comm, msgs, mvalid, caps[0], owners[0])
+            routed = route(0, msgs, mvalid)
             link_round = routed.link_flits
             hop_round = routed.hop_hist
             die_round = routed.die_hist
             sents = [routed.sent]
             spillv = [routed.spill_valid]
-            edges = jnp.zeros_like(drops)
-            applied = jnp.zeros_like(drops)
-            n_replay = jnp.zeros_like(drops)
-            hbm_win = jnp.zeros_like(drops)
+            with jax.named_scope("telemetry"):
+                edges = jnp.zeros_like(drops)
+                applied = jnp.zeros_like(drops)
+                n_replay = jnp.zeros_like(drops)
+                hbm_win = jnp.zeros_like(drops)
 
             def count_windows(acc, rvalid):
                 # Per-tile DMA accounting of the streamed T2: each range
@@ -711,8 +770,16 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
                 # covering windows (the double buffer) — what the machine
                 # transfers, independent of the emulation's vectorized
                 # staging.
-                return acc + comm.run(
-                    lambda me, v: 2 * v.sum(dtype=jnp.int32), rvalid)
+                with jax.named_scope("telemetry"):
+                    return acc + comm.run(
+                        lambda me, v: 2 * v.sum(dtype=jnp.int32), rvalid)
+
+            def add_work(ch, edges, applied, work):
+                if ch.work == "edges":
+                    edges = edges + work
+                elif ch.work == "updates":
+                    applied = applied + work
+                return edges, applied
 
             for i in range(1, K):
                 if edge_space == "hbm" and chans[i - 1].work == "edges":
@@ -721,18 +788,18 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
                     make_mid(i), leg_shard(i), st, routed.recv,
                     routed.recv_valid,
                     routed.spill, routed.spill_valid, dyn_pops)
-                drops = drops + d
-                n_pop = n_pop + npop
-                n_push = n_push + npush
-                n_replay = n_replay + nspill
-                if chans[i - 1].work == "edges":
-                    edges = edges + work
-                elif chans[i - 1].work == "updates":
-                    applied = applied + work
-                routed = net.route(comm, msgs, mvalid, caps[i], owners[i])
-                link_round = link_round + routed.link_flits
-                hop_round = hop_round + routed.hop_hist
-                die_round = die_round + routed.die_hist
+                with jax.named_scope("telemetry"):
+                    drops = drops + d
+                    n_pop = n_pop + npop
+                    n_push = n_push + npush
+                    n_replay = n_replay + nspill
+                    edges, applied = add_work(chans[i - 1], edges, applied,
+                                              work)
+                routed = route(i, msgs, mvalid)
+                with jax.named_scope("telemetry"):
+                    link_round = link_round + routed.link_flits
+                    hop_round = hop_round + routed.hop_hist
+                    die_round = die_round + routed.die_hist
                 sents.append(routed.sent)
                 spillv.append(routed.spill_valid)
             if edge_space == "hbm" and chans[K - 1].work == "edges":
@@ -741,120 +808,124 @@ def make_round(comm, net, cfg: EngineConfig, prog: Program, e_chunk: int,
                                            routed.recv, routed.recv_valid,
                                            routed.spill,
                                            routed.spill_valid)
-        drops = drops + d
-        n_replay = n_replay + nspill
-        if chans[K - 1].work == "edges":
-            edges = edges + work
-        elif chans[K - 1].work == "updates":
-            applied = applied + work
+        with jax.named_scope("telemetry"):
+            drops = drops + d
+            n_replay = n_replay + nspill
+            edges, applied = add_work(chans[K - 1], edges, applied, work)
 
-        # NoC telemetry: global per-link occupancy of this round, and the
-        # per-tile pressure fed back into next round's TSU budgets.
-        link_round = comm.psum(link_round)
-        hop_round = comm.psum(hop_round)
-        die_round = comm.psum(die_round)
-        st = st._replace(net_pressure=comm.run(
-            lambda me, lf: net.pressure(me, lf), link_round))
-
-        pending = comm.psum(comm.run(_pending, st))
-        nxt = comm.psum(comm.run(_next_pending, st))
-        if cfg.mode == "bsp":
-            do_swap = (pending == 0) & (nxt > 0)
-            st = comm.run(_bsp_swap, st, _bcast(comm, do_swap))
-            epochs_inc = do_swap
-            pending = pending + nxt
-        else:
-            epochs_inc = jnp.zeros_like(pending)
+            # NoC telemetry: global per-link occupancy of this round, and
+            # the per-tile pressure fed back into next round's TSU budgets.
+            link_round = comm.psum(link_round)
+            hop_round = comm.psum(hop_round)
+            die_round = comm.psum(die_round)
+            st = st._replace(net_pressure=comm.run(
+                lambda me, lf: net.pressure(me, lf), link_round))
 
         glob = comm.to_global
-        msgs_vec = jnp.stack([glob(comm.psum(s)) for s in sents])
-        spills_vec = jnp.stack([
-            glob(comm.psum(comm.run(
-                lambda me, v: v.sum(dtype=jnp.int32), sv)))
-            for sv in spillv])
-        link_g = glob(link_round)
-        edges_g = glob(comm.psum(edges))
-        applied_g = glob(comm.psum(applied))
+        with jax.named_scope("control"):
+            pending = comm.psum(comm.run(_pending, st))
+            nxt = comm.psum(comm.run(_next_pending, st))
+            if cfg.mode == "bsp":
+                do_swap = (pending == 0) & (nxt > 0)
+                st = comm.run(_bsp_swap, st, _bcast(comm, do_swap))
+                epochs_inc = do_swap
+                pending = pending + nxt
+            else:
+                epochs_inc = jnp.zeros_like(pending)
 
-        # Cycle/energy model (repro.perf): the round costs its slowest
-        # tile's compute plus the busiest link's serialization, each link
-        # priced by its class (local / ruche express / torus wrap).  An
-        # HBM-resident shard additionally pays t_hbm/e_hbm per streamed
-        # edge word (the per-space pricing split; the terms are absent —
-        # not zero-multiplied — on all-VMEM runs, keeping them bit-stable
-        # with the pre-memspace model).
-        streaming = edge_space == "hbm"
-        hbm_edges_tile = hbm_win * jnp.int32(window) if streaming else None
-        hw_g = glob(comm.psum(hbm_win))
-        he_g = hw_g * jnp.int32(window) if streaming else hw_g
-        comp = tile_compute_cycles(pp, n_pop, n_push, n_replay, edges,
-                                   applied, hbm_edges=hbm_edges_tile)
-        cyc_round = (jnp.float32(pp.t_round) + glob(comm.pmax(comp))
-                     + (link_g.astype(jnp.float32) * t_hop).max())
-        energy_round = round_energy_pj(
-            pp, comm.size, edges_g, applied_g, msgs_vec.sum(),
-            spills_vec.sum(), link_g, e_hop, cyc_round,
-            hbm_edges_g=he_g if streaming else None)
-        cycles_acc, c_cyc = kahan_add(stats.cycles, kcomp[0], cyc_round)
-        energy_acc, c_en = kahan_add(stats.energy_pj, kcomp[1],
-                                     energy_round)
+        with jax.named_scope("telemetry"):
+            msgs_vec = jnp.stack([glob(comm.psum(s)) for s in sents])
+            spills_vec = jnp.stack([
+                glob(comm.psum(comm.run(
+                    lambda me, v: v.sum(dtype=jnp.int32), sv)))
+                for sv in spillv])
+            link_g = glob(link_round)
+            edges_g = glob(comm.psum(edges))
+            applied_g = glob(comm.psum(applied))
 
-        stats = Stats(
-            rounds=stats.rounds + 1,
-            epochs=stats.epochs + glob(epochs_inc),
-            msgs=stats.msgs + msgs_vec,
-            spills=stats.spills + spills_vec,
-            edges_scanned=stats.edges_scanned + edges_g,
-            updates_applied=stats.updates_applied + applied_g,
-            drops=stats.drops + glob(comm.psum(drops)),
-            work_max=stats.work_max + glob(comm.pmax(edges)),
-            flits_per_link=stats.flits_per_link + link_g,
-            max_link_occupancy=jnp.maximum(stats.max_link_occupancy,
-                                           link_g.max()),
-            hop_histogram=stats.hop_histogram + glob(hop_round),
-            die_crossings=stats.die_crossings + glob(die_round),
-            cycles=cycles_acc,
-            energy_pj=energy_acc,
-            launches=stats.launches + jnp.int32(launch_tally.n),
-            hbm_windows=stats.hbm_windows + hw_g,
-            hbm_edges=stats.hbm_edges + he_g,
-            migrated_vertices=stats.migrated_vertices,
-            migration_cycles=stats.migration_cycles,
-            migration_pj=stats.migration_pj,
-        )
+            # Cycle/energy model (repro.perf): the round costs its slowest
+            # tile's compute plus the busiest link's serialization, each
+            # link priced by its class (local / ruche express / torus
+            # wrap).  An HBM-resident shard additionally pays t_hbm/e_hbm
+            # per streamed edge word (the per-space pricing split; the
+            # terms are absent — not zero-multiplied — on all-VMEM runs,
+            # keeping them bit-stable with the pre-memspace model).
+            streaming = edge_space == "hbm"
+            hbm_edges_tile = hbm_win * jnp.int32(window) if streaming \
+                else None
+            hw_g = glob(comm.psum(hbm_win))
+            he_g = hw_g * jnp.int32(window) if streaming else hw_g
+            comp = tile_compute_cycles(pp, n_pop, n_push, n_replay, edges,
+                                       applied, hbm_edges=hbm_edges_tile)
+            cyc_round = (jnp.float32(pp.t_round) + glob(comm.pmax(comp))
+                         + (link_g.astype(jnp.float32) * t_hop).max())
+            energy_round = round_energy_pj(
+                pp, comm.size, edges_g, applied_g, msgs_vec.sum(),
+                spills_vec.sum(), link_g, e_hop, cyc_round,
+                hbm_edges_g=he_g if streaming else None)
+            cycles_acc, c_cyc = kahan_add(stats.cycles, kcomp[0], cyc_round)
+            energy_acc, c_en = kahan_add(stats.energy_pj, kcomp[1],
+                                         energy_round)
+
+            stats = Stats(
+                rounds=stats.rounds + 1,
+                epochs=stats.epochs + glob(epochs_inc),
+                msgs=stats.msgs + msgs_vec,
+                spills=stats.spills + spills_vec,
+                edges_scanned=stats.edges_scanned + edges_g,
+                updates_applied=stats.updates_applied + applied_g,
+                drops=stats.drops + glob(comm.psum(drops)),
+                work_max=stats.work_max + glob(comm.pmax(edges)),
+                flits_per_link=stats.flits_per_link + link_g,
+                max_link_occupancy=jnp.maximum(stats.max_link_occupancy,
+                                               link_g.max()),
+                hop_histogram=stats.hop_histogram + glob(hop_round),
+                die_crossings=stats.die_crossings + glob(die_round),
+                cycles=cycles_acc,
+                energy_pj=energy_acc,
+                launches=stats.launches + jnp.int32(launch_tally.n),
+                hbm_windows=stats.hbm_windows + hw_g,
+                hbm_edges=stats.hbm_edges + he_g,
+                migrated_vertices=stats.migrated_vertices,
+                migration_cycles=stats.migration_cycles,
+                migration_pj=stats.migration_pj,
+            )
         if tracing:
-            # Flight recorder (repro.trace): pure reads of telemetry the
-            # round already computed, plus trace-only reductions — nothing
-            # here feeds back into state, values or Stats (the invariance
-            # contract).  All recorded values are global/replicated, like
-            # Stats, so shard_map carries an identical ring per device.
-            comp_all = comm.to_global(comm.all_gather(comp))  # (T,) f32
-            occ = comm.run(
-                lambda me, s: jnp.stack([q.count for q in s.queues]), st)
-            # the TSU's source grant, recomputed from the same pre-round
-            # state stage_first arbitrated on (same integer math)
-            src_grant = comm.run(
-                lambda me, s, f: _budgets(cfg, prog, qcaps, pops, s,
-                                          plimit, f)[0], st0, full)
-            tbuf = record_round(tbuf, dict(
-                cyc=cyc_round,
-                cyc_total=cycles_acc,
-                tile_busy=comp_all,
-                crit_tile=jnp.argmax(comp_all).astype(jnp.int32),
-                msgs=msgs_vec,
-                spills=spills_vec,
-                qdepth=glob(comm.psum(occ)),
-                qdepth_max=glob(comm.pmax(occ)),
-                chan_budget=glob(comm.psum(dyn_pops)),
-                src_budget=glob(comm.psum(src_grant)),
-                link_cls=(cls_onehot * link_g[None, :]).sum(axis=1),
-                launches=jnp.int32(launch_tally.n),
-                hbm_windows=hw_g,
-                frontier=glob(comm.psum(comm.run(
-                    lambda me, s: s.frontier.sum(dtype=jnp.int32), st))),
-                pending=glob(pending),
-            ), round_ix, cfg.trace_every)
-        return st, stats, (c_cyc, c_en), tbuf, glob(pending)
+            with jax.named_scope("recorder"):
+                # Flight recorder (repro.trace): pure reads of telemetry
+                # the round already computed, plus trace-only reductions —
+                # nothing here feeds back into state, values or Stats (the
+                # invariance contract).  All recorded values are
+                # global/replicated, like Stats, so shard_map carries an
+                # identical ring per device.
+                comp_all = comm.to_global(comm.all_gather(comp))  # (T,) f32
+                occ = comm.run(
+                    lambda me, s: jnp.stack([q.count for q in s.queues]), st)
+                # the TSU's source grant, recomputed from the same pre-round
+                # state stage_first arbitrated on (same integer math)
+                src_grant = comm.run(
+                    lambda me, s, f: _budgets(cfg, prog, qcaps, pops, s,
+                                              plimit, f)[0], st0, full)
+                tbuf = record_round(tbuf, dict(
+                    cyc=cyc_round,
+                    cyc_total=cycles_acc,
+                    tile_busy=comp_all,
+                    crit_tile=jnp.argmax(comp_all).astype(jnp.int32),
+                    msgs=msgs_vec,
+                    spills=spills_vec,
+                    qdepth=glob(comm.psum(occ)),
+                    qdepth_max=glob(comm.pmax(occ)),
+                    chan_budget=glob(comm.psum(dyn_pops)),
+                    src_budget=glob(comm.psum(src_grant)),
+                    link_cls=(cls_onehot * link_g[None, :]).sum(axis=1),
+                    launches=jnp.int32(launch_tally.n),
+                    hbm_windows=hw_g,
+                    frontier=glob(comm.psum(comm.run(
+                        lambda me, s: s.frontier.sum(dtype=jnp.int32), st))),
+                    pending=glob(pending),
+                ), round_ix, cfg.trace_every)
+        with jax.named_scope("control"):
+            return st, stats, (c_cyc, c_en), tbuf, glob(pending)
 
     return rnd
 
@@ -916,14 +987,17 @@ def run_engine(comm, cfg: EngineConfig, alg, shard: GraphShard,
 
     def cond(carry):
         _, _, _, _, pending, r = carry
-        return (pending > 0) & (r < cfg.max_rounds)
+        with jax.named_scope("control"):
+            return (pending > 0) & (r < cfg.max_rounds)
 
     def body(carry):
         st, stats, kcomp, tbuf, _, r = carry
         st, stats, kcomp, tbuf, pending = rnd(st, stats, kcomp, tbuf)
-        return st, stats, kcomp, tbuf, pending, r + 1
+        with jax.named_scope("control"):
+            return st, stats, kcomp, tbuf, pending, r + 1
 
-    pending0 = comm.to_global(comm.psum(comm.run(_pending, st)))
+    with jax.named_scope("init"):
+        pending0 = comm.to_global(comm.psum(comm.run(_pending, st)))
     zf = jnp.zeros((), jnp.float32)
     st, stats, _, tbuf, pending, _ = jax.lax.while_loop(
         cond, body,

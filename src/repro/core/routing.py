@@ -35,8 +35,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.queues import histogram
-
 EMPTY = jnp.int32(-1)  # head-flit value marking an empty network slot
 
 
@@ -117,10 +115,3 @@ def route_tasks(comm, msgs: jax.Array, valid: jax.Array, dest: jax.Array,
     recv = comm.a2a(buf)
     recv_valid = recv[..., 0] >= 0
     return Routed(recv, recv_valid, spill, spill_valid, n_sent)
-
-
-def route_stats(comm, valid: jax.Array, dest: jax.Array, num_shards: int):
-    """Per-destination message histogram (for NoC-balance benchmarks)."""
-    def local(_me, v, d):
-        return histogram(d, v, num_shards)
-    return comm.run(local, valid, dest)

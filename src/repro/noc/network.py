@@ -143,9 +143,10 @@ class IdealAllToAll:
         r = route_tasks(comm, msgs, valid, dest, capacity)
 
         def telemetry(_me, d, v, spill_v, n_sent):
-            link = histogram(d, v & ~spill_v, T)  # per-ingress-port flits
-            hop = jnp.stack([jnp.zeros((), jnp.int32), n_sent])
-            return link, hop, n_sent[None]  # die_hist: everything in bin 0
+            with jax.named_scope("link_count"):
+                link = histogram(d, v & ~spill_v, T)  # per-ingress-port
+                hop = jnp.stack([jnp.zeros((), jnp.int32), n_sent])
+                return link, hop, n_sent[None]  # die_hist: all in bin 0
 
         link, hop, die = comm.run(telemetry, dest, valid, r.spill_valid,
                                   r.sent)
@@ -242,13 +243,16 @@ class _Grid2D:
             dr, dc = d // cols, d % cols
             hx, use_x = line_usage(jnp.broadcast_to(c_me, dc.shape), dc,
                                    cols, wrap, ruche, die_x)
-            hy, _ = line_usage(jnp.broadcast_to(r_me, dr.shape), dr,
-                               rows, wrap, ruche, die_y)
-            cross = (jnp.abs(_die_coord(dc, die_x) - _die_coord(c_me, die_x))
-                     + jnp.abs(_die_coord(dr, die_y)
-                               - _die_coord(r_me, die_y)))
+            with jax.named_scope("link_count"):
+                hy, _ = line_usage(jnp.broadcast_to(r_me, dr.shape), dr,
+                                   rows, wrap, ruche, die_y)
+                cross = (jnp.abs(_die_coord(dc, die_x)
+                                 - _die_coord(c_me, die_x))
+                         + jnp.abs(_die_coord(dr, die_y)
+                                   - _die_coord(r_me, die_y)))
+                hops = hx + hy
             claims = (use_x & v[:, None, None]).sum(0, dtype=jnp.int32)
-            return dc, hx + hy, cross, use_x, claims
+            return dc, hops, cross, use_x, claims
 
         def phase_x(me, m, v, dc, hops, cross, use_x, base):
             # X leg: ride the own-row line to the destination column; also
@@ -260,10 +264,12 @@ class _Grid2D:
                                                T, capacity)
             sent_mask = (v & ok) & ~ep_spill
             spill_v = v & ~sent_mask
-            lx = jnp.zeros((rows, N_CHANNELS, cols), jnp.int32).at[r_me].add(
-                (use_x & sent_mask[:, None, None]).sum(0, dtype=jnp.int32))
-            hop = histogram(hops, sent_mask, n_hop)
-            die = histogram(cross, sent_mask, n_die)
+            with jax.named_scope("link_count"):
+                lx = jnp.zeros((rows, N_CHANNELS, cols), jnp.int32).at[
+                    r_me].add((use_x & sent_mask[:, None, None]).sum(
+                        0, dtype=jnp.int32))
+                hop = histogram(hops, sent_mask, n_hop)
+                die = histogram(cross, sent_mask, n_die)
             return buf, m, spill_v, lx.reshape(-1), hop, die
 
         def x_base(me, all_claims):
@@ -301,8 +307,10 @@ class _Grid2D:
                                                dr * cols + c_me, T, capacity)
             sent_mask = (v & ok) & ~ep_spill
             spill_v = v & ~sent_mask
-            ly = jnp.zeros((cols, N_CHANNELS, rows), jnp.int32).at[c_me].add(
-                (use_y & sent_mask[:, None, None]).sum(0, dtype=jnp.int32))
+            with jax.named_scope("link_count"):
+                ly = jnp.zeros((cols, N_CHANNELS, rows), jnp.int32).at[
+                    c_me].add((use_y & sent_mask[:, None, None]).sum(
+                        0, dtype=jnp.int32))
             return (buf, rec, spill_v, sent_mask.sum(dtype=jnp.int32),
                     ly.reshape(-1))
 
@@ -325,7 +333,8 @@ class _Grid2D:
 
         spill = jnp.concatenate([spill1, spill2], axis=-2)
         spill_v = jnp.concatenate([spill1_v, spill2_v], axis=-1)
-        link = jnp.concatenate([lx, ly], axis=-1)
+        with jax.named_scope("link_count"):
+            link = jnp.concatenate([lx, ly], axis=-1)
         return NetRouted(recv, recv[..., 0] >= 0, spill, spill_v, sent,
                          link, hop, die)
 

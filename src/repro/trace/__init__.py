@@ -15,7 +15,10 @@ work-imbalance / queue-depth summary.  CLI::
     PYTHONPATH=src python -m repro.trace summarize [--preset rmat-small]
     PYTHONPATH=src python -m repro.trace export --out run.perfetto.json
 
-See DESIGN.md "Tracing & observability".
+See DESIGN.md "Tracing & observability".  The recorder prices the
+*modelled* machine; where the device's own time goes is read from a
+profiler trace through the engine's named scopes and the drivers' host
+spans (DESIGN.md "Device legs and host spans").
 """
 from repro.trace.buffer import (SERIES_FIELDS, TraceBuf, record_round,
                                 zero_trace)
