@@ -208,6 +208,7 @@ _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([^ ]+) .*\{$")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([^ ]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%([^ ,}]+)")
+_WHILE = re.compile(r" while\(%")
 _REF = re.compile(r"%([^\s,(){}]+)")
 _TRANSFORM = re.compile(r"^(?:\w+\()+|\)+$")
 
@@ -238,9 +239,12 @@ def hlo_legs(hlo_text: str) -> dict[str, str]:
       one, else of the nearest that feeds it: the leg it serves;
     * failing both, an instruction of a called computation (the body of
       a loop the compiler made) takes the scope of the innermost
-      instruction calling it that has one.
+      instruction calling it that has one;
+    * a traced ``while`` instruction itself, whose own time is the
+      loop's between its operations, is ``control``.
     """
     scoped, calls, comps, refs = {}, {}, {}, {}
+    loops = set()
     body = None
     for line in hlo_text.splitlines():
         m = _HLO_COMPUTATION.match(line)
@@ -255,6 +259,8 @@ def hlo_legs(hlo_text: str) -> dict[str, str]:
             calls[name] = called and called.group(1)
             refs[name] = _REF.findall(line[m.end():])
             body.append(name)
+            if op and _WHILE.search(line, m.end()):
+                loops.add(name)
     caller = {c: n for n, r in refs.items() for c in r if c in comps}
 
     def fused(name):
@@ -295,6 +301,8 @@ def hlo_legs(hlo_text: str) -> dict[str, str]:
 
     legs = {}
     for name, leg in scoped.items():
+        if leg == "unscoped" and name in loops:
+            leg = "control"
         if leg == "unscoped":
             leg = nearest(name, users)
         if leg == "unscoped":

@@ -46,24 +46,26 @@ class Routed(NamedTuple):
     recv_valid:  (T*capacity,) bool — decoded from the head flit.
     spill:       (N, W) int32 — local copies of messages that did not fit.
     spill_valid: (N,) bool.
-    sent:        () int32 — number of messages actually sent by this device.
+    per_dest:    (T,) int32 — messages this device sent to each device
+                 (their sum is all it sent).
     """
 
     recv: jax.Array
     recv_valid: jax.Array
     spill: jax.Array
     spill_valid: jax.Array
-    sent: jax.Array
+    per_dest: jax.Array
 
 
 def bin_by_owner(msgs, valid, dest, num_shards, capacity):
     """Pack ``msgs`` into per-destination slots of a (T*capacity, W) buffer.
 
-    Returns (send_buf, spill_msgs, spill_valid, n_sent).  Rows
+    Returns (send_buf, spill_msgs, spill_valid, per_dest).  Rows
     ``[d*capacity:(d+1)*capacity]`` of ``send_buf`` are addressed to shard
     ``d``; empty slots have head flit -1.  FIFO order within each destination
     is preserved; messages beyond ``capacity`` for a destination are returned
-    as spill (masked in place).
+    as spill (masked in place).  ``per_dest[d]`` is the number of messages
+    sent to shard ``d``, ``min(valid messages to d, capacity)``.
 
     One stable sort by destination carries the messages as payload, so
     each destination's messages sit contiguously in FIFO order: slot
@@ -95,8 +97,7 @@ def bin_by_owner(msgs, valid, dest, num_shards, capacity):
     buf = jnp.where(live[..., None], blocks, EMPTY)
     _, fits = jax.lax.sort((ar_s, fits_s), num_keys=1)
     spill_valid = valid & ~fits
-    n_sent = fits_s.sum(dtype=jnp.int32)
-    return buf.reshape(num_shards * capacity, w), msgs, spill_valid, n_sent
+    return buf.reshape(num_shards * capacity, w), msgs, spill_valid, fill
 
 
 def route_tasks(comm, msgs: jax.Array, valid: jax.Array, dest: jax.Array,
@@ -111,7 +112,8 @@ def route_tasks(comm, msgs: jax.Array, valid: jax.Array, dest: jax.Array,
     def local_bin(_me, m, v, d):
         return bin_by_owner(m, v, d, T, capacity)
 
-    buf, spill, spill_valid, n_sent = comm.run(local_bin, msgs, valid, dest)
+    buf, spill, spill_valid, per_dest = comm.run(local_bin, msgs, valid,
+                                                 dest)
     recv = comm.a2a(buf)
     recv_valid = recv[..., 0] >= 0
-    return Routed(recv, recv_valid, spill, spill_valid, n_sent)
+    return Routed(recv, recv_valid, spill, spill_valid, per_dest)

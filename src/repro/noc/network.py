@@ -142,16 +142,17 @@ class IdealAllToAll:
         dest = comm.run(lambda _me, m: jnp.clip(dest_fn(m), 0, T - 1), msgs)
         r = route_tasks(comm, msgs, valid, dest, capacity)
 
-        def telemetry(_me, d, v, spill_v, n_sent):
+        def telemetry(_me, per_port):
+            # per-ingress-port flits are the binning's per-destination
+            # counts; every delivery is one hop
             with jax.named_scope("link_count"):
-                link = histogram(d, v & ~spill_v, T)  # per-ingress-port
+                n_sent = per_port.sum(dtype=jnp.int32)
                 hop = jnp.stack([jnp.zeros((), jnp.int32), n_sent])
-                return link, hop, n_sent[None]  # die_hist: all in bin 0
+                return n_sent, hop, n_sent[None]  # die_hist: all in bin 0
 
-        link, hop, die = comm.run(telemetry, dest, valid, r.spill_valid,
-                                  r.sent)
+        sent, hop, die = comm.run(telemetry, r.per_dest)
         return NetRouted(r.recv, r.recv_valid, r.spill, r.spill_valid,
-                         r.sent, link, hop, die)
+                         sent, r.per_dest, hop, die)
 
     def pressure(self, me, link_flits):
         """Occupancy of this tile's ingress port last round."""

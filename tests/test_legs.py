@@ -172,6 +172,29 @@ def test_hlo_legs_follows_fusions_and_the_data_an_op_serves():
     assert legs["t"] == "fold" and legs["s"] == "fold"
 
 
+def test_hlo_legs_charges_the_round_loop_itself_to_control():
+    hlo = "\n".join([
+        "%cond (c: s32[]) -> pred[] {",
+        "  %c = s32[] parameter(0)",
+        "  ROOT %lt.1 = pred[] compare(%c), "
+        'metadata={op_name="a/while/cond/control/lt"}',
+        "}",
+        "%body (b: s32[]) -> s32[] {",
+        "  %b = s32[] parameter(0)",
+        "  ROOT %sort.2 = s32[] sort(%b), "
+        'metadata={op_name="a/while/body/route/sort"}',
+        "}",
+        "ENTRY %main (x: s32[]) -> s32[] {",
+        "  %x = s32[] parameter(0)",
+        '  %add.3 = s32[] add(%x, %x), metadata={op_name="a/init/add"}',
+        "  ROOT %while.4 = s32[] while(%add.3), condition=%cond, "
+        'body=%body, metadata={op_name="a/while"}',
+        "}"])
+    legs = alg.hlo_legs(hlo)
+    assert legs["while.4"] == "control"      # not the init it is fed by
+    assert legs["sort.2"] == "route" and legs["add.3"] == "init"
+
+
 def test_leg_map_names_the_operations_of_the_engine_call(pg):
     cfg = small_cfg()
     legs = alg.engine_leg_map(pg, BFS, cfg)

@@ -14,17 +14,29 @@ The system invariants under test:
     agree to float tolerance (delivery rounds differ, so scatter-adds
     re-associate);
   * with no capacity pressure, flit telemetry is conserved:
-    sum(flits_per_link) == sum(hops * hop_histogram).
+    sum(flits_per_link) == sum(hops * hop_histogram);
+  * the ideal crossbar's per-port flits, taken from the binning's
+    per-destination counts, equal a histogram of the delivered messages,
+    so a whole engine run is bitwise the run that recounts them.
 """
+import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import algorithms as alg
+from repro.core import engine
 from repro.core import reference as ref
 from repro.core.comm import LocalComm
 from repro.core.engine import EngineConfig
 from repro.core.graph import CSRGraph, rmat_edges
+from repro.core.queues import histogram
 from repro.noc import (LOCAL_BWD, LOCAL_FWD, RUCHE_BWD, RUCHE_FWD,
                        Hier2D, IdealAllToAll, Mesh2D, Ruche, Torus2D,
                        admit, grid_shape, line_usage, make_network)
@@ -272,3 +284,153 @@ def test_make_network_selects_backend():
     assert isinstance(net, Ruche) and net.ruche == 3
     with pytest.raises(ValueError):
         make_network(small_cfg(noc="hypercube"), 16)
+
+
+# --------------------------------------------------------------------------
+# The ideal crossbar's per-port counts come from the binning.
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("density", [0.0, 0.6, 1.0])
+@pytest.mark.parametrize("capacity", [2, 1000], ids=["spill", "above_n"])
+@pytest.mark.parametrize("T", [4, 8, 16])
+def test_ideal_link_flits_equal_delivered_histogram(T, capacity, density):
+    """The per-port counts equal the scatter-add histogram of the
+    delivered messages that the crossbar used to recount them with."""
+    n, chunk = 4 * T, 16  # four messages per destination on average
+    rng = np.random.default_rng(1000 * T + capacity + int(10 * density))
+    idx = rng.integers(0, T * chunk, (T, n))
+    msgs = jnp.stack([jnp.asarray(idx, jnp.int32),
+                      jnp.asarray(idx * 7, jnp.int32)], axis=2)
+    valid = jnp.asarray(rng.random((T, n)) < density)
+    r = IdealAllToAll(T).route(LocalComm(T), msgs, valid, capacity,
+                               lambda m: m[..., 0] // chunk)
+    want = jax.vmap(lambda d, v: histogram(d, v, T))(
+        jnp.asarray(idx // chunk, jnp.int32), valid & ~r.spill_valid)
+    np.testing.assert_array_equal(np.asarray(r.link_flits),
+                                  np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(r.link_flits).sum(1),
+                                  np.asarray(r.sent))
+    spilled = bool(np.asarray(r.spill_valid).any())
+    assert spilled == (capacity == 2 and density > 0)
+
+
+SHARD_MAP_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+    from repro.core.comm import AxisComm, LocalComm
+    from repro.core.queues import histogram
+    from repro.noc import IdealAllToAll
+
+    for T in (4, 8, 16):
+        for capacity in (2, 1000):
+            for density in (0.0, 0.6, 1.0):
+                n, chunk = 4 * T, 16
+                rng = np.random.default_rng(T + capacity)
+                idx = rng.integers(0, T * chunk, (T, n))
+                msgs = jnp.stack([jnp.asarray(idx, jnp.int32),
+                                  jnp.asarray(idx * 7, jnp.int32)], axis=2)
+                valid = jnp.asarray(rng.random((T, n)) < density)
+                dest_fn = lambda m: m[..., 0] // chunk
+                net = IdealAllToAll(T)
+
+                def body(m, v):
+                    r = net.route(AxisComm("x", T), m[0], v[0], capacity,
+                                  dest_fn)
+                    return r.link_flits[None], r.spill_valid[None]
+
+                mesh = jax.make_mesh((T,), ("x",),
+                                     devices=jax.devices()[:T])
+                link, spill = map(np.asarray, jax.jit(jax.shard_map(
+                    body, mesh=mesh, in_specs=(P("x"), P("x")),
+                    out_specs=(P("x"), P("x")), check_vma=False))(msgs,
+                                                                 valid))
+                want = jax.vmap(lambda d, v: histogram(d, v, T))(
+                    jnp.asarray(idx // chunk, jnp.int32), valid & ~spill)
+                local = net.route(LocalComm(T), msgs, valid, capacity,
+                                  dest_fn)
+                np.testing.assert_array_equal(link, np.asarray(want))
+                np.testing.assert_array_equal(link,
+                                              np.asarray(local.link_flits))
+                assert spill.any() == (capacity == 2 and density > 0)
+    print("IDEAL-SHARD-MAP-OK")
+""")
+
+
+@pytest.mark.slow  # needs forced host devices, so a subprocess
+def test_ideal_link_flits_equal_delivered_histogram_under_shard_map():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", SHARD_MAP_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "IDEAL-SHARD-MAP-OK" in out.stdout
+
+
+@pytest.mark.parametrize("program", ["bfs", "pagerank"])
+def test_engine_bitwise_equal_to_recounting_crossbar(pg, g, program,
+                                                     monkeypatch):
+    """A whole run on the shipped crossbar equals one on a crossbar that
+    recounts its link flits, in every value and every Stats field, with
+    budgets under which the ingress pressure crosses the TSU's limit."""
+    cfg = small_cfg(f_pop=16, r_pop=16, u_pop=32, max_t2=16,
+                    cap_route_range=4, cap_route_update=16,
+                    cap_rangeq=256, cap_updq=4096)
+    root = root_of(g)
+
+    def run():
+        if program == "bfs":
+            return alg.bfs(pg, root, cfg)
+        return alg.pagerank(pg, iters=2, cfg=cfg)
+
+    recounted = []  # one entry per traced route of the recounting crossbar
+
+    @dataclasses.dataclass(frozen=True)
+    class RecountingIdeal(IdealAllToAll):
+        """The ideal crossbar that recounts its per-port flits from the
+        delivered messages with a scatter-add histogram."""
+
+        def route(self, comm, msgs, valid, capacity, dest_fn):
+            recounted.append(capacity)
+            r = super().route(comm, msgs, valid, capacity, dest_fn)
+            T = self.T
+            link = comm.run(
+                lambda _me, m, v, sv: histogram(
+                    jnp.clip(dest_fn(m), 0, T - 1), v & ~sv, T),
+                msgs, valid, r.spill_valid)
+            return r._replace(link_flits=link)
+
+    shipped = run()
+    # a fresh jit of the engine call, traced with the recounting crossbar
+    shipped_network = engine.make_network
+    monkeypatch.setattr(
+        engine, "make_network",
+        lambda c, T: RecountingIdeal(T) if c.noc == "ideal"
+        else shipped_network(c, T))
+    shipped_call = alg._local_call.__wrapped__
+
+    def fresh_call(prog, cfg, T, e_chunk, v_chunk, shard, value, frontier,
+                   acc):
+        return shipped_call(prog, cfg, T, e_chunk, v_chunk, shard, value,
+                            frontier, acc)
+
+    monkeypatch.setattr(alg, "_local_call", jax.jit(
+        fresh_call, static_argnames=("prog", "cfg", "T", "e_chunk",
+                                     "v_chunk")))
+    recount = run()
+    assert recounted  # the engine was traced anew, on the recounting one
+
+    np.testing.assert_array_equal(shipped.values, recount.values)
+    for field in shipped.stats._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(shipped.stats, field)),
+            np.asarray(getattr(recount.stats, field)), err_msg=field)
+    assert shipped.epochs == recount.epochs
+    # the TSU saw a hot fabric: some port's ingress passed the limit
+    plimit = IdealAllToAll(pg.T).pressure_limit(
+        cfg, (cfg.cap_route_range, cfg.cap_route_update))
+    assert int(shipped.stats.max_link_occupancy) > plimit
+    assert int(shipped.stats.rounds) > 0

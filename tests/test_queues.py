@@ -69,7 +69,7 @@ def test_route_conserves_messages(T, capacity, data):
     dest = jnp.asarray(idx // chunk, jnp.int32)
     comm = LocalComm(T)
     r = route_tasks(comm, msgs, jnp.asarray(valid), dest, capacity)
-    sent = int(np.asarray(r.sent).sum())
+    sent = int(np.asarray(r.per_dest).sum())
     spilled = int(np.asarray(r.spill_valid).sum())
     assert sent + spilled == int(valid.sum())
     # delivery: each device receives exactly the sent messages it owns
@@ -116,7 +116,8 @@ def test_route_fifo_per_destination():
 
 def _bin_loop(msgs, valid, dest, num_shards, capacity):
     """The row-by-row slot claim that ``bin_by_owner`` vectorizes: each
-    valid message takes the next free slot of its destination, or spills."""
+    valid message takes the next free slot of its destination, or spills.
+    Returns the buffer, the spill mask and the slots used per destination."""
     n, w = msgs.shape
     buf = np.full((num_shards * capacity, w), -1, np.int64)
     used = [0] * num_shards
@@ -130,7 +131,7 @@ def _bin_loop(msgs, valid, dest, num_shards, capacity):
             used[d] += 1
         else:
             spill[i] = True
-    return buf, spill
+    return buf, spill, np.asarray(used)
 
 
 @pytest.mark.parametrize("T,capacity,n,w", [
@@ -142,11 +143,14 @@ def test_bin_by_owner_matches_row_by_row_claim(T, capacity, n, w):
     msgs = rng.integers(0, 1000, (n, w))
     dest = rng.integers(0, T, n)
     valid = rng.random(n) < 0.7
-    buf, spill_msgs, spill_valid, sent = bin_by_owner(
+    buf, spill_msgs, spill_valid, per_dest = bin_by_owner(
         jnp.asarray(msgs, jnp.int32), jnp.asarray(valid),
         jnp.asarray(dest, jnp.int32), T, capacity)
-    want_buf, want_spill = _bin_loop(msgs, valid, dest, T, capacity)
+    want_buf, want_spill, want_used = _bin_loop(msgs, valid, dest, T,
+                                                capacity)
     np.testing.assert_array_equal(np.asarray(buf), want_buf)
     np.testing.assert_array_equal(np.asarray(spill_valid), want_spill)
     np.testing.assert_array_equal(np.asarray(spill_msgs), msgs)
-    assert int(sent) == int(valid.sum() - want_spill.sum())
+    np.testing.assert_array_equal(np.asarray(per_dest), want_used)
+    assert int(np.asarray(per_dest).sum()) == int(valid.sum()
+                                                  - want_spill.sum())
